@@ -101,6 +101,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.roofline.analysis import (HBM_BW, LINK_BW, PEAK_FLOPS,
                                      analytic_hbm_bytes, model_flops_for)
+from repro.runtime.compile_cache import use_compile_cache
 
 # NOTE: the roofline hillclimb cells need 512 virtual host devices; the
 # mesh-cell path below calls dryrun.ensure_virtual_devices() explicitly
@@ -200,6 +201,7 @@ def main():
                          "(applied before jax init; sweep_shard "
                          "defaults to 8)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.cell == "ga_fitness":
         run_ga_fitness()     # no device mesh needed
         return

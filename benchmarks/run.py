@@ -38,6 +38,9 @@ def main() -> None:
     args.fast = not args.full
     be = args.backend
     from repro.core import sweep
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from . import (fig3_motivation, fig8_latency_hbm, fig9_10_scaling,
                    fig11_pipelining, fig12_lowbw, fig13_ablation,

@@ -72,6 +72,7 @@ from .hw import HWConfig
 from .pipelining_jax import chain_priorities_jnp, sgs_instance
 from .workload import (Partition, Task, clamp_partition_to_domain,
                        uniform_partition)
+from .x64 import cumsum_seq, x64
 
 __all__ = [
     "OBJECTIVES",
@@ -312,7 +313,7 @@ def _fitness_one(batch: int, redistribution: bool, async_exec: bool,
             [jnp.ones((n - 1,), dtype=Px.dtype),
              jnp.zeros((1,), dtype=Px.dtype)])
         b = seg * notlast
-        seg_id = jnp.cumsum(jnp.concatenate(
+        seg_id = cumsum_seq(jnp.concatenate(
             [jnp.zeros((1,), dtype=Px.dtype), b[:-1]]))
         onehot = (seg_id[:, None] == jnp.arange(n)[None, :]).astype(
             Px.dtype)
@@ -549,7 +550,7 @@ def gradient_seeds(task: Task, hw: HWConfig, options: EvalOptions,
                           int(cfg.seed_steps))
     S = int(cfg.seed_starts)
 
-    with jax.experimental.enable_x64():
+    with x64():
         cpj = {k: jnp.asarray(v) for k, v in cp.items()}
         cdj = {k: jnp.asarray(v) for k, v in cd.items()}
         cov = jnp.asarray(co)
@@ -726,7 +727,7 @@ def cosearch_islands(
 
     n = len(tasks[0])
     X, Y = hws[0].X, hws[0].Y
-    with jax.experimental.enable_x64():
+    with x64():
         cpj = {k: jnp.asarray(v) for k, v in cp.items()}
         cdj = {k: jnp.asarray(v) for k, v in cd.items()}
         win_j = {k: jnp.asarray(v) for k, v in win.items()}

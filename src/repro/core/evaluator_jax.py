@@ -14,7 +14,7 @@ Structure:
     axis and vmaps over them);
   * :func:`population_fn` = ``jit(vmap(single-candidate))`` — the GA
     fitness path; :func:`grid_fn` adds a second vmap over the grid axis;
-  * all entry points run under ``jax.experimental.enable_x64()`` — cycle
+  * all entry points run under the :func:`repro.core.x64.x64` scope — cycle
     counts overflow float32 mantissas (same float64 rule as the numpy
     path) and the scope keeps x64 from leaking into the rest of the
     repo's float32 jax code.
@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from .evaluator import EvalOptions
 from .netsim_jax import waterfill_times
+from .x64 import cumsum_seq, x64
 
 __all__ = [
     "EvalConsts",
@@ -227,7 +228,7 @@ def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
     t1 = (jnp.maximum(left_x, right_x) / c["row_bw"][None]).max(axis=-1)
     rowbytes = Px * N[:, None] * B                             # [n,X]
     t2 = (rowbytes / c["row_bw"][None]).max(axis=-1)
-    cumf = jnp.cumsum(Px, axis=-1) / jnp.maximum(M[:, None], 1.0)
+    cumf = cumsum_seq(Px, axis=-1) / jnp.maximum(M[:, None], 1.0)
     cumf_next = jnp.concatenate([cumf[1:], cumf[-1:]], axis=0)
     if X > 1:
         crossing = jnp.abs(cumf - cumf_next)[:, : X - 1] * M[:, None]
@@ -295,7 +296,7 @@ def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
 def to_device(consts: EvalConsts) -> EvalConsts:
     """Convert a constant bundle to float64 device arrays once, so repeated
     population calls skip host→device transfer (no-op on device arrays)."""
-    with jax.experimental.enable_x64():
+    with x64():
         return {k: jnp.asarray(v) for k, v in consts.items()}
 
 
@@ -345,7 +346,7 @@ def _run_x64(fn, consts: EvalConsts, Px, Py, collectors, redist
              ) -> dict[str, np.ndarray]:
     """Shared call wrapper: float64 conversion inside the x64 scope,
     numpy float64 outputs with the numpy backend's keys/shapes."""
-    with jax.experimental.enable_x64():
+    with x64():
         cj = {k: jnp.asarray(v) for k, v in consts.items()}
         out = fn(cj,
                  jnp.asarray(Px, dtype=jnp.float64),

@@ -53,6 +53,7 @@ from .evaluator_jax import _eval_single
 from .ga import MOVE_ATTEMPTS
 from .hw import HWConfig
 from .workload import Partition, Task, partition_domain
+from .x64 import x64
 
 __all__ = ["run_ga_jax", "solve_islands"]
 
@@ -303,7 +304,7 @@ def solve_islands(
 
     n = len(tasks[0])
     X, Y = hws[0].X, hws[0].Y
-    with jax.experimental.enable_x64():
+    with x64():
         consts_j = {k: jnp.asarray(v) for k, v in consts.items()}
         win_j = {k: jnp.asarray(v) for k, v in win.items()}
         f8 = lambda a: jnp.asarray(a, dtype=jnp.float64)

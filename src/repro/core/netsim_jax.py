@@ -15,8 +15,8 @@ Port of the vectorized numpy engine in :mod:`repro.core.netsim`
 Shapes are the only compile-time statics: the :mod:`repro.core.topology`
 link space is a pure function of (X, Y) — every memory placement /
 bandwidth cell of a grid is data, not structure — so one executable
-serves the entire grid. All entry points run under
-``jax.experimental.enable_x64()`` (same float64 rule, and the same
+serves the entire grid. All entry points run under the
+:func:`repro.core.x64.x64` scope (same float64 rule, and the same
 leak-containment scoping, as :mod:`repro.core.evaluator_jax`).
 
 Numerics note: each waterfilling iteration retires the argmin-share
@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .netsim import EPS_BYTES, MAX_EVENTS
+from .x64 import x64
 
 __all__ = ["waterfill_rates", "waterfill_times", "simulate_pull_batch"]
 
@@ -147,7 +148,7 @@ def simulate_pull_batch(caps, incs, msgs,
     from . import sweep_shard
 
     G = int(np.shape(caps)[0])
-    with jax.experimental.enable_x64():
+    with x64():
         args = (jnp.asarray(caps, dtype=jnp.float64),
                 jnp.asarray(incs, dtype=jnp.float64),
                 jnp.asarray(msgs, dtype=jnp.float64))

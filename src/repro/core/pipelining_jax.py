@@ -29,7 +29,7 @@ bit-for-bit — the contract is *bit-identical* makespans and start times,
 stronger than the §8 evaluator backends' rtol-1e-9 parity
 (``tests/test_core_pipelining_engines.py`` enforces it).
 
-All entry points run under ``jax.experimental.enable_x64()`` (same
+All entry points run under the :func:`repro.core.x64.x64` scope (same
 float64 rule and leak-containment scoping as
 :mod:`repro.core.netsim_jax`).
 """
@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .pipelining import chain_priorities
+from .x64 import cumsum_seq, x64
 
 __all__ = ["schedule_batch", "sgs_instance", "chain_priorities_jnp"]
 
@@ -54,8 +55,10 @@ def chain_priorities_jnp(dur_flat):
     callers that build priorities *inside* a jitted objective
     (:mod:`repro.core.cosearch`); :func:`schedule_batch` keeps computing
     them on host so the serial-engine bit-parity contract is pinned to
-    one accumulation order."""
-    return jnp.cumsum(dur_flat[::-1])[::-1]
+    one accumulation order. The sequential scan adds in ``np.cumsum``'s
+    order, so wherever float64 is IEEE (not on a TPU, which emulates it)
+    these priorities are bitwise equal to the host ones."""
+    return cumsum_seq(dur_flat[::-1])[::-1]
 
 
 @functools.lru_cache(maxsize=None)
@@ -147,7 +150,7 @@ def schedule_batch(segments_grid: np.ndarray, batch: int,
     if L == 0 or batch == 0:
         return {"makespan": np.zeros(G), "starts": np.zeros((G, batch, L))}
     prio = np.stack([chain_priorities(dur[g]) for g in range(G)])
-    with jax.experimental.enable_x64():
+    with x64():
         args = (jnp.asarray(dur), jnp.asarray(prio))
         if sweep_shard.resolve_devices(devices, G) == "sharded":
             ms, starts = sweep_shard.sharded_grid_call(

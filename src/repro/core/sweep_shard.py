@@ -54,7 +54,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from .evaluator import DEVICE_MODES
@@ -121,12 +120,12 @@ def _sharded_fn(inner, mesh, batched: tuple):
     mask) — engines pass lru-cached inner functions, so the jit cache
     never grows per call. ``batched[i]`` shards positional arg ``i``'s
     leading axis over the whole mesh; False replicates (hyperparams,
-    shared RNG keys). ``check_rep=False``: per-shard computation is
+    shared RNG keys). ``check_vma=False``: per-shard computation is
     independent, there is no replication to infer across lanes."""
     axes = PartitionSpec(tuple(mesh.axis_names))
     in_specs = tuple(axes if b else PartitionSpec() for b in batched)
-    return jax.jit(shard_map(inner, mesh=mesh, in_specs=in_specs,
-                             out_specs=axes, check_rep=False))
+    return jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                                 out_specs=axes, check_vma=False))
 
 
 def sharded_grid_call(inner, args: tuple, batched: tuple, n_points: int,
@@ -135,7 +134,7 @@ def sharded_grid_call(inner, args: tuple, batched: tuple, n_points: int,
 
     ``inner`` must be the engine's *unjitted* vmapped function (shapes
     [G, ...] on batched args); callers invoke this inside their own
-    ``jax.experimental.enable_x64()`` scope — padding concatenates in
+    :func:`repro.core.x64.x64` scope — padding concatenates in
     jnp and must not downcast float64. Pads the grid axis to a multiple
     of the device count, dispatches one compiled shard_map call, slices
     outputs back to ``n_points``."""

@@ -141,13 +141,6 @@ class CalibratedHW:
 # too slow off-TPU to time honestly)
 # --------------------------------------------------------------------------
 
-def _cost_dict(compiled) -> dict:
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):       # older jax returns [dict]
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
-
-
 def _measure(fn, args, *, reps: int) -> tuple[float, float, float]:
     """(calib_flops, calib_bytes, median_wall_s) for fn(*args).
 
@@ -157,9 +150,11 @@ def _measure(fn, args, *, reps: int) -> tuple[float, float, float]:
     """
     import jax
 
+    from ..launch.dryrun import cost_analysis_dict
+
     with calibration():
         calib = jax.jit(fn).lower(*args).compile()
-    cd = _cost_dict(calib)
+    cd = cost_analysis_dict(calib)
     flops = float(cd.get("flops", 0.0))
     nbytes = float(cd.get("bytes accessed", 0.0))
 

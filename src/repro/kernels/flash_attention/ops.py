@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import jax
 
+from .._common import check_pallas_backend
 from .blockwise import blockwise_attention
 from .kernel import flash_attention
 from .ref import attention_ref  # noqa: F401
@@ -15,9 +16,9 @@ def attention(q, k, v, *, causal=True, window=None, softcap=None,
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas or interpret:
+        check_pallas_backend(interpret)
         return flash_attention(
             q, k, v, causal=causal, window=window, softcap=softcap,
-            scale=scale,
-            interpret=interpret or jax.default_backend() != "tpu")
+            scale=scale, interpret=interpret)
     return blockwise_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, scale=scale, **kw)

@@ -4,9 +4,9 @@ Grid (B·H, n_chunks), chunks innermost; the (K, K) WKV state is VMEM
 scratch carried across chunk steps. Unlike SSD, the decay here is
 *per-channel*, so the intra-chunk pairwise term needs per-channel decay
 alignment; the kernel keeps chunks small (Lc ≤ 64) and computes the
-(Lc, Lc) interaction with one fori_loop over the chunk's rows feeding the
-MXU (row i's decayed query against all j ≤ i−1 keys), which avoids any
-(Lc, Lc, K) VMEM tensor.
+(Lc, Lc) interaction with a static loop over the chunk's rows feeding
+the MXU (row i's query against all j ≤ i−1 keys decayed relative to
+row i), which avoids any (Lc, Lc, K) VMEM tensor.
 """
 from __future__ import annotations
 
@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .._common import cumsum_rows
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
@@ -32,28 +34,23 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
     lw = lw_ref[0].astype(jnp.float32)   # (Lc, K) log decay ≤ 0
     u = u_ref[0].astype(jnp.float32)     # (1, K) bonus
 
-    cum = jnp.cumsum(lw, axis=0)                       # (Lc, K)
+    cum = cumsum_rows(lw)                              # (Lc, K)
     cum_im1 = cum - lw                                 # cum_{i-1}
     # intra-chunk pairwise term:
     #   A[i, j] = Σ_c r_i[c]·exp(cum_{i-1}[c] − cum_j[c])·k_j[c],  j < i
-    # Computed as (r_i ∘ exp(cum_{i-1})) · (k_j ∘ exp(−cum_j))ᵀ row by
-    # row; exponents are normalized per row i so every exp argument stays
-    # ≤ 0 (cum is monotonically decreasing in i).
-    rq = r * jnp.exp(cum_im1)                          # (Lc, K)
-
-    def row(i, y):
+    # one row at a time, with exponents normalized per row i so every
+    # exp argument of a kept key stays ≤ 0 (cum decreases in i). Rows
+    # are static slices: Mosaic has no dynamic_slice lowering.
+    rows = jax.lax.broadcasted_iota(jnp.int32, (Lc, 1), 0)
+    y_intra = jnp.zeros((Lc, v.shape[-1]), jnp.float32)
+    for i in range(1, Lc):                             # row 0: no j < 0
         # keys decayed relative to row i: exp(cum_{i-1} − cum_j) ≤ 1 ∀ j<i
-        kd = k * jnp.exp(cum_im1[i] - cum)             # (Lc, K)
-        a_i = jnp.sum(jnp.where(
-            (jax.lax.broadcasted_iota(jnp.int32, (Lc, 1), 0) < i),
-            r[i] * kd, 0.0), axis=-1)                  # (Lc,)
-        y_i = jnp.dot(a_i[None, :], v,
-                      preferred_element_type=jnp.float32)[0]
-        return y.at[i].set(y_i)
-
-    y_intra = jax.lax.fori_loop(
-        0, Lc, row, jnp.zeros((Lc, v.shape[-1]), jnp.float32))
-    del rq
+        kd = jnp.where(rows < i, k * jnp.exp(cum_im1[i:i + 1] - cum), 0.0)
+        a_i = jax.lax.dot_general(
+            r[i:i + 1], kd, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (1, Lc)
+        y_i = jnp.dot(a_i, v, preferred_element_type=jnp.float32)
+        y_intra += jnp.where(rows == i, y_i, 0.0)
     # bonus diagonal
     diag = jnp.sum(r * u * k, axis=-1, keepdims=True)  # (Lc, 1)
     y_intra += diag * v
@@ -62,7 +59,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
                       preferred_element_type=jnp.float32)
     # state: S' = D(exp(cum_L))·S + Σ_j (k_j ∘ exp(cum_L − cum_j)) ⊗ v_j
     decay_end = jnp.exp(cum[-1:] - cum)                # (Lc, K)
-    s_ref[...] = (s_ref[...] * jnp.exp(cum[-1])[:, None]
+    s_ref[...] = (s_ref[...] * jnp.exp(cum[-1:]).T
                   + jnp.dot((k * decay_end).T, v,
                             preferred_element_type=jnp.float32))
     o_ref[0, ...] = (y_intra + y_inter).astype(o_ref.dtype)

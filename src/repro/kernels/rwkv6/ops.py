@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import jax
 
+from .._common import check_pallas_backend
 from .chunked import wkv6_chunked
 from .kernel import wkv6 as wkv6_pallas
 from .ref import wkv6_ref  # noqa: F401
@@ -13,7 +14,6 @@ def wkv6(r, k, v, w, u, *, chunk: int = 32,
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas or interpret:
-        return wkv6_pallas(
-            r, k, v, w, u, chunk=chunk,
-            interpret=interpret or jax.default_backend() != "tpu")
+        check_pallas_backend(interpret)
+        return wkv6_pallas(r, k, v, w, u, chunk=chunk, interpret=interpret)
     return wkv6_chunked(r, k, v, w, u, chunk=chunk)[0]

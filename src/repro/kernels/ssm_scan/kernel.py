@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .._common import cumsum_rows
+
 
 def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, o_ref, h_ref, *,
                 n_chunks: int, Lc: int):
@@ -35,7 +37,7 @@ def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, o_ref, h_ref, *,
     Bm = b_ref[0].astype(jnp.float32)       # (Lc, N)
     Cm = c_ref[0].astype(jnp.float32)       # (Lc, N)
 
-    cum = jnp.cumsum(da, axis=0)            # (Lc, 1)
+    cum = cumsum_rows(da)                   # (Lc, 1)
     # intra-chunk: y[i] = Σ_{j<=i} exp(cum_i - cum_j)·dt_j·(C_i·B_j)·x_j
     diff = cum - cum.T                      # (Lc, Lc)
     tri = (jax.lax.broadcasted_iota(jnp.int32, (Lc, Lc), 0)
@@ -50,7 +52,9 @@ def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, o_ref, h_ref, *,
     # state update: h' = exp(cum_L)·h + Σ_j exp(cum_L - cum_j)·dt_j·B_j⊗x_j
     decay_end = jnp.exp(cum[-1:] - cum)     # (Lc, 1)
     dB = Bm * (dt * decay_end)              # (Lc, N)
-    h_ref[...] = (h_ref[...] * jnp.exp(cum[-1])
+    # exp(cum_L) as a scalar: Mosaic cannot broadcast a (1, 1) tile over
+    # both sublanes and lanes.
+    h_ref[...] = (h_ref[...] * jnp.exp(jnp.sum(da))
                   + jnp.dot(dB.T, x, preferred_element_type=jnp.float32))
     o_ref[0, ...] = y.astype(o_ref.dtype)
 

@@ -63,12 +63,9 @@ _BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s32": 4, "s64": 8,
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` across jax versions: jax<=0.4.x
-    returns a one-element list of dicts, jax>=0.5 returns the dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
+    """``Compiled.cost_analysis()`` as a dict (empty where the backend
+    reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def collective_bytes(hlo_text: str) -> dict[str, float]:

@@ -6,16 +6,27 @@ data parallelism (DCN between pods carries only gradient reductions).
 
 A FUNCTION, not a module constant — importing this module must never
 touch jax device state (the dry-run sets XLA_FLAGS before first init).
+
+Every mesh is built with ``Auto`` axes, the mode the sharding rules
+(``launch/specs.py``, ``sharding/logical.py``) are written for:
+``with_sharding_constraint`` accepts only ``Auto`` axes, and
+``jax.make_mesh`` defaults to ``Explicit`` ones.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int | None = None, *, pod: bool = False):
@@ -40,7 +51,6 @@ def make_debug_mesh(n_devices: int | None = None, *, pod: bool = False):
                          f"only {len(devs)} exist")
     devs = devs[:n]
     if pod and n >= 8 and n % 4 == 0:
-        return jax.make_mesh((2, 2, n // 4), ("pod", "data", "model"),
-                             devices=devs)
+        return _mesh((2, 2, n // 4), ("pod", "data", "model"), devs)
     d = 2 if n % 2 == 0 and n >= 2 else 1
-    return jax.make_mesh((d, n // d), ("data", "model"), devices=devs)
+    return _mesh((d, n // d), ("data", "model"), devs)

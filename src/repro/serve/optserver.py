@@ -59,6 +59,7 @@ from concurrent.futures import Future
 from typing import Any
 
 from ..core import sweep
+from ..runtime.compile_cache import use_compile_cache
 from ..runtime.fault_tolerance import StragglerMonitor
 from .cache_store import CacheStore
 from .coalesce import BadRequest, CallKey, OptRequest
@@ -439,6 +440,7 @@ def main(argv=None) -> None:
                     help="§15 sweep sharding mode (default: per-request)")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     srv = OptServer(store_path=args.store, max_batch=args.max_batch,
                     devices=args.devices, log=print)
     futs = [srv.submit(r) for r in _demo_requests(args.requests)]

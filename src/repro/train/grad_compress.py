@@ -44,8 +44,7 @@ def compressed_psum_mean(x, err, axis_names: tuple[str, ...]):
     summed = jax.lax.psum(deq, axis_names)
     n = 1
     for a in axis_names:
-        # psum(1) == axis size; jax.lax.axis_size only exists in jax>=0.5
-        n *= jax.lax.psum(1, a)
+        n *= jax.lax.axis_size(a)
     return summed / n, new_err
 
 
@@ -57,8 +56,6 @@ def make_compressed_allreduce(mesh, axes: tuple[str, ...], specs=None):
     the reduced axes (replicated by default — the pure-DP case where each
     data-parallel rank holds a full gradient replica to be averaged).
     """
-    from jax.experimental.shard_map import shard_map
-
     def run(grads, err):
         tdef = jax.tree.structure(grads)
         in_specs = specs if specs is not None else jax.tree.map(
@@ -72,9 +69,9 @@ def make_compressed_allreduce(mesh, axes: tuple[str, ...], specs=None):
             errs = tdef.unflatten([l[1] for l in leaves])
             return means, errs
 
-        return shard_map(kernel, mesh=mesh,
-                         in_specs=(in_specs, in_specs),
-                         out_specs=(in_specs, in_specs),
-                         check_rep=False)(grads, err)
+        return jax.shard_map(kernel, mesh=mesh,
+                             in_specs=(in_specs, in_specs),
+                             out_specs=(in_specs, in_specs),
+                             check_vma=False)(grads, err)
 
     return run

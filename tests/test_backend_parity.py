@@ -162,3 +162,70 @@ def test_x64_does_not_leak():
     Evaluator(task, hw, backend="jax").evaluate(
         uniform_partition(task, 4, 4))
     assert jnp.asarray(1.0).dtype == jnp.float32
+
+
+def _entry_points():
+    """One small call of every jitted engine's public entry point."""
+    from repro.core import CoSearchConfig, MIQPConfig, run_cosearch, sweep
+    from repro.core.ga_jax import run_ga_jax
+    from repro.core.miqp_jax import solve_lattice_batch
+    from repro.core.netsim import MeshNet
+    from repro.core.pipelining_jax import schedule_batch
+
+    task = Task("chain", [GemmOp("g0", M=256, K=128, N=256),
+                          GemmOp("g1", M=256, K=256, N=128, chained=True)])
+    hw = make_hw("A", 2)
+    opts = EvalOptions(redistribution=True)
+    pts = [sweep.EvalPoint(task, hw, opts)] * 2
+    return {
+        "evaluator": lambda: Evaluator(task, hw, backend="jax").evaluate(
+            uniform_partition(task, 2, 2)),
+        "eval_sweep": lambda: sweep.eval_sweep(pts, cache=False,
+                                               devices="single"),
+        "eval_sweep_sharded": lambda: sweep.eval_sweep(
+            pts, cache=False, devices="sharded"),
+        "netsim": lambda: sweep.netsim_sweep(
+            [MeshNet(2, 2, 64.0, 128.0, [0])], 1e6, cache=False),
+        "ga": lambda: run_ga_jax(task, hw, "latency", opts, GAConfig(
+            population=4, generations=2, patience=2)),
+        "miqp": lambda: solve_lattice_batch([task], [hw], opts, "latency",
+                                            MIQPConfig(backend="jax")),
+        "sgs": lambda: schedule_batch(np.ones((1, 2, 3)), 2),
+        "cosearch": lambda: run_cosearch(task, hw, "edp", opts,
+                                         CoSearchConfig(
+                                             population=4, generations=2,
+                                             seed_steps=2, seed_starts=2)),
+    }
+
+
+@pytest.mark.parametrize("engine", ["evaluator", "eval_sweep",
+                                    "eval_sweep_sharded", "netsim", "ga",
+                                    "miqp", "sgs", "cosearch"])
+def test_engine_leaves_x64_off(engine):
+    """Each engine computes in float64 inside its own scope and leaves
+    the global setting (float32 default) as it found it."""
+    import jax
+    import jax.numpy as jnp
+
+    _entry_points()[engine]()
+    assert not jax.config.jax_enable_x64
+    assert jnp.asarray(1.0).dtype == jnp.float32
+
+
+@pytest.mark.parametrize("shape,axis", [((257,), -1), ((6, 16), -1),
+                                        ((16, 5), 0), ((0,), -1)])
+def test_cumsum_seq_bitwise_equals_numpy(shape, axis):
+    """The sequential scan adds in np.cumsum's order: bitwise equal on
+    random float64 input spanning many magnitudes (where a different
+    summation order would round differently), signed zeros included."""
+    from repro.core.x64 import cumsum_seq, x64
+
+    rng = np.random.default_rng(sum(shape) + 10 * abs(axis))
+    x = rng.normal(size=shape) * 10.0 ** rng.integers(-8, 9, size=shape)
+    if x.size:
+        x.flat[0] = -0.0
+    want = np.cumsum(x, axis=axis)
+    with x64():
+        got = np.asarray(cumsum_seq(x, axis=axis))
+    assert got.dtype == np.float64
+    assert got.tobytes() == want.tobytes()

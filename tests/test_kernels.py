@@ -149,3 +149,36 @@ def test_wkv6_bf16():
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=6e-2, atol=6e-2)
+
+
+# ------------------------------------------------------- ops dispatch
+def _ops_calls():
+    from repro.kernels.flash_attention.ops import attention
+    from repro.kernels.gemm.ops import matmul as matmul_op
+    from repro.kernels.rwkv6.ops import wkv6 as wkv6_op
+    from repro.kernels.ssm_scan.ops import ssm_scan as ssm_scan_op
+
+    x4 = jnp.ones((1, 16, 2, 8))
+    return {
+        "gemm": lambda **kw: matmul_op(jnp.ones((8, 8)), jnp.ones((8, 8)),
+                                       **kw),
+        "flash_attention": lambda **kw: attention(x4, x4, x4, **kw),
+        "rwkv6": lambda **kw: wkv6_op(x4, x4, x4, 0.5 * x4,
+                                      jnp.ones((2, 8)), chunk=8, **kw),
+        "ssm_scan": lambda **kw: ssm_scan_op(
+            x4, jnp.ones((1, 16, 2)), -jnp.ones((2,)), x4[:, :, :1],
+            x4[:, :, :1], jnp.ones((2,)), chunk=8, **kw),
+    }
+
+
+@pytest.mark.parametrize("op", ["gemm", "flash_attention", "rwkv6",
+                                "ssm_scan"])
+def test_ops_refuse_pallas_off_tpu_without_interpret(op):
+    """Off a TPU, asking for the Pallas kernel raises unless the caller
+    asks for interpret mode; the default picks the XLA path."""
+    assert jax.default_backend() != "tpu"
+    call = _ops_calls()[op]
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        call(use_pallas=True)
+    np.testing.assert_allclose(call(use_pallas=True, interpret=True),
+                               call(), rtol=2e-3, atol=2e-3)

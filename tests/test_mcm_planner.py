@@ -107,12 +107,13 @@ def test_plan_roundtrips_into_dryrun_artifact():
     launch/dryrun — execute_plan lowers, compiles, and costs the plan's
     knobs and returns a JSON-serializable artifact record."""
     from repro.launch.dryrun import execute_plan
+    from repro.launch.mesh import make_debug_mesh
 
     arch = "smollm-360m"
     cfg = get_config(arch, reduced=True)
     n = len(jax.devices())
     d = 2 if n % 2 == 0 and n >= 2 else 1
-    mesh = jax.make_mesh((d, n // d), ("data", "model"))
+    mesh = make_debug_mesh(n)
     pr = plan(cfg, (d, n // d), 64, 8, layers=cfg.n_layers, ga_budget=2)
     shape = "__test_plan_roundtrip"
     SHAPE_DEFS[shape] = dict(seq_len=64, global_batch=8, kind="prefill")

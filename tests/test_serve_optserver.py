@@ -349,7 +349,31 @@ def test_cli_demo_runs(monkeypatch, capsys, tmp_path):
                 for _ in range(n)]
 
     monkeypatch.setattr(mod, "_demo_requests", tiny_traffic)
+    # With the variable set, the CLI's compile-cache helper changes no
+    # process-wide setting (JAX read the variable at import).
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     mod.main(["--requests", "3",
               "--store", str(tmp_path / "cli-store.bin")])
     out = capsys.readouterr().out
     assert "served 3/3 requests" in out
+
+
+def test_compile_cache_helper(monkeypatch, tmp_path):
+    """The CLIs' cache helper defers to JAX_COMPILATION_CACHE_DIR and
+    otherwise points JAX at the checkout's fixed .jax_cache/."""
+    import jax
+
+    from repro.runtime.compile_cache import DEFAULT_DIR, use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert use_compile_cache() == str(DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+        assert DEFAULT_DIR.name == ".jax_cache"
+        assert (DEFAULT_DIR.parent / "chip_smoke.py").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

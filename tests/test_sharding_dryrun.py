@@ -8,6 +8,7 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs import get_config
+from repro.launch.mesh import make_debug_mesh
 from repro.sharding.logical import sanitize_spec, shard, use_rules
 from repro.sharding.partition_specs import (activation_rules, data_specs,
                                             param_specs)
@@ -16,9 +17,7 @@ N_DEV = len(jax.devices())
 
 
 def small_mesh():
-    n = N_DEV
-    d = 2 if n % 2 == 0 and n >= 2 else 1
-    return jax.make_mesh((d, n // d), ("data", "model"))
+    return make_debug_mesh(N_DEV)
 
 
 def test_sanitize_spec_drops_nondivisible():
