@@ -36,6 +36,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..runtime.spans import span
 from .evaluator import EvalOptions
 from .netsim_jax import waterfill_times
 from .x64 import cumsum_seq, x64
@@ -73,6 +74,14 @@ CONST_KEYS = (
     "B", "bw_nop_min", "R", "C",
     "e_sram", "e_mem", "e_nop", "e_mac",
 )
+
+#: Per-lane work counts of the flow netsim's two call sites (``dist``:
+#: the input-load flows, ``coll``: the collection flows), [n] per
+#: candidate in ``congestion="flow"`` mode: event-loop iterations and
+#: waterfilling iterations (:func:`repro.core.netsim_jax.waterfill_times`).
+#: :func:`_run_x64` takes them out of the outputs, so no record carries
+#: them.
+LANE_KEYS = ("dist_events", "dist_fills", "coll_events", "coll_fills")
 
 
 def consts_from_evaluator(ev) -> EvalConsts:
@@ -178,15 +187,18 @@ def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
                   + inW[:, None, :]).reshape(n, X * Y) * d_routed
 
         def dist_one(b):
-            _, done, _ = waterfill_times(c["flow_cap"], c["dist_inc"], b)
-            return done
+            _, done, _, events, fills = waterfill_times(
+                c["flow_cap"], c["dist_inc"], b)
+            return done, events, fills
 
         def coll_one(b):
-            t, _, _ = waterfill_times(c["flow_cap"], c["coll_inc"], b)
-            return t
+            t, _, _, events, fills = waterfill_times(
+                c["flow_cap"], c["coll_inc"], b)
+            return t, events, fills
 
-        dist_done = jax.vmap(dist_one)(demand).reshape(n, X, Y)
-        t_coll_flow = jax.vmap(coll_one)(
+        dist_done, dist_events, dist_fills = jax.vmap(dist_one)(demand)
+        dist_done = dist_done.reshape(n, X, Y)
+        t_coll_flow, coll_events, coll_fills = jax.vmap(coll_one)(
             chunk.reshape(n, X * Y) * c_routed)
         t_in = jnp.maximum(t_off_in, dist_done.max(axis=(-1, -2)))
     else:
@@ -279,7 +291,7 @@ def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
     E_nop = c["e_nop"] * nop_bh.sum()
 
     energy = E_sram + E_mac + E_mem + E_nop
-    return {
+    out = {
         "latency": latency,
         "energy": energy,
         "edp": energy * latency,
@@ -291,6 +303,10 @@ def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
         "E_mem": E_mem,
         "E_nop": E_nop,
     }
+    if flow_mode:
+        out.update(dist_events=dist_events, dist_fills=dist_fills,
+                   coll_events=coll_events, coll_fills=coll_fills)
+    return out
 
 
 def to_device(consts: EvalConsts) -> EvalConsts:
@@ -342,25 +358,37 @@ def grid_fn(redistribution: bool, async_exec: bool, energy_mode: str,
                                congestion))
 
 
+def _host_bytes(*trees) -> int:
+    """Bytes of the numpy arrays among the leaves: what ``jnp.asarray``
+    copies to the device (device arrays move nothing)."""
+    return sum(a.nbytes for a in jax.tree_util.tree_leaves(trees)
+               if isinstance(a, np.ndarray))
+
+
 def _run_x64(fn, consts: EvalConsts, Px, Py, collectors, redist
-             ) -> dict[str, np.ndarray]:
+             ) -> tuple[dict[str, np.ndarray], dict[str, np.ndarray]]:
     """Shared call wrapper: float64 conversion inside the x64 scope,
-    numpy float64 outputs with the numpy backend's keys/shapes."""
+    numpy float64 outputs with the numpy backend's keys/shapes, and
+    apart from them the flow mode's per-lane counts (:data:`LANE_KEYS`;
+    empty in regime mode)."""
+    genomes = (Px, Py, collectors, redist)
     with x64():
-        cj = {k: jnp.asarray(v) for k, v in consts.items()}
-        out = fn(cj,
-                 jnp.asarray(Px, dtype=jnp.float64),
-                 jnp.asarray(Py, dtype=jnp.float64),
-                 jnp.asarray(collectors, dtype=jnp.float64),
-                 jnp.asarray(redist, dtype=jnp.float64))
-        return {k: np.asarray(v) for k, v in out.items()}
+        with span("eval.to_device", bytes=_host_bytes(consts, genomes)):
+            cj = {k: jnp.asarray(v) for k, v in consts.items()}
+            args = [jnp.asarray(a, dtype=jnp.float64) for a in genomes]
+        with span("eval.call"):
+            out = fn(cj, *args)
+        with span("eval.fetch"):
+            out = {k: np.asarray(v) for k, v in out.items()}
+    lanes = {k: out.pop(k) for k in LANE_KEYS if k in out}
+    return out, lanes
 
 
 def batch_evaluate(consts: EvalConsts, opts: EvalOptions,
                    Px, Py, collectors, redist) -> dict[str, np.ndarray]:
     """Population-batched evaluation (genomes [P,...]) — the GA path."""
     return _run_x64(population_fn(*_static_key(opts)),
-                    consts, Px, Py, collectors, redist)
+                    consts, Px, Py, collectors, redist)[0]
 
 
 def grid_evaluate(consts_stack: EvalConsts, opts: EvalOptions,
@@ -371,7 +399,13 @@ def grid_evaluate(consts_stack: EvalConsts, opts: EvalOptions,
 
     ``devices`` (DESIGN.md §15) shards the grid axis across local
     devices via :mod:`repro.core.sweep_shard`; outputs are bitwise
-    identical to the single-device call."""
+    identical to the single-device call.
+
+    In flow mode each netsim call site leaves one ``eval.lanes`` marker
+    span: its ``lanes`` (grid x population x ops), the event-loop
+    iterations summed over them and their maximum (the lockstep count of
+    the vmapped loop), and the same two for the waterfilling iterations
+    (whose lockstep count is at least ``fills_max``)."""
     G = int(np.shape(Px)[0])
     fn = grid_fn(*_static_key(opts))
     from . import sweep_shard
@@ -382,4 +416,15 @@ def grid_evaluate(consts_stack: EvalConsts, opts: EvalOptions,
         def fn(*args):
             return sweep_shard.sharded_grid_call(
                 inner, args, (True,) * 5, G)
-    return _run_x64(fn, consts_stack, Px, Py, collectors, redist)
+    out, lanes = _run_x64(fn, consts_stack, Px, Py, collectors, redist)
+    for site in ("dist", "coll"):
+        if f"{site}_events" in lanes:
+            events = lanes[f"{site}_events"]
+            fills = lanes[f"{site}_fills"]
+            with span("eval.lanes", site=site, lanes=int(events.size),
+                      events_sum=int(events.sum(dtype=np.int64)),
+                      events_max=int(events.max(initial=0)),
+                      fills_sum=int(fills.sum(dtype=np.int64)),
+                      fills_max=int(fills.max(initial=0))):
+                pass
+    return out

@@ -48,8 +48,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax, random
 
+from ..runtime.spans import span
 from .evaluator import EvalOptions, Evaluator
-from .evaluator_jax import _eval_single
+from .evaluator_jax import _eval_single, _host_bytes
 from .ga import MOVE_ATTEMPTS
 from .hw import HWConfig
 from .workload import Partition, Task, partition_domain
@@ -249,32 +250,36 @@ def solve_islands(
     pop = cfg.population
     elite = min(cfg.elite, pop - 1)
 
-    evs = [Evaluator(t, h, options, backend="numpy")
-           for t, h in zip(tasks, hws)]
-    keys0 = evs[0].consts().keys()
-    consts = {k: np.stack([ev.consts()[k] for ev in evs]) for k in keys0}
-    win = {"lo_x": [], "hi_x": [], "lo_y": [], "hi_y": []}
-    inits = []
-    for t, h in zip(tasks, hws):
-        lo, hi = partition_domain(t, h.X, h.Y, h.R, h.C, cfg.slack)
-        win["lo_x"].append(lo[:, 0])
-        win["hi_x"].append(hi[:, 0])
-        win["lo_y"].append(lo[:, 1])
-        win["hi_y"].append(hi[:, 1])
+    with span("ga.consts", islands=G) as sp:
+        evs = [Evaluator(t, h, options, backend="numpy")
+               for t, h in zip(tasks, hws)]
+        keys0 = evs[0].consts().keys()
+        consts = {k: np.stack([ev.consts()[k] for ev in evs])
+                  for k in keys0}
+        win = {"lo_x": [], "hi_x": [], "lo_y": [], "hi_y": []}
+        for t, h in zip(tasks, hws):
+            lo, hi = partition_domain(t, h.X, h.Y, h.R, h.C, cfg.slack)
+            win["lo_x"].append(lo[:, 0])
+            win["hi_x"].append(hi[:, 0])
+            win["lo_y"].append(lo[:, 1])
+            win["hi_y"].append(hi[:, 1])
+        win = {k: np.stack(v).astype(np.float64) for k, v in win.items()}
+        sp.set_metadata(bytes=_host_bytes(consts, win))
+    with span("ga.init", islands=G, population=pop):
         # Shared host init (per-island RNG seeded by cfg.seed alone, so a
         # point's result never depends on its position in the grid).
-        inits.append(_random_population_vec(
-            np.random.default_rng(cfg.seed), t, h, cfg, pop))
-    if seeds is not None:
-        if len(seeds) != G:
-            raise ValueError(f"seeds must align with islands: "
-                             f"{len(seeds)} != {G}")
-        for g, props in enumerate(seeds):
-            Px0, Py0 = inits[g][0], inits[g][1]
-            for j, p in enumerate(props[:pop - 1]):
-                Px0[j + 1] = p.Px
-                Py0[j + 1] = p.Py
-    win = {k: np.stack(v).astype(np.float64) for k, v in win.items()}
+        inits = [_random_population_vec(np.random.default_rng(cfg.seed),
+                                        t, h, cfg, pop)
+                 for t, h in zip(tasks, hws)]
+        if seeds is not None:
+            if len(seeds) != G:
+                raise ValueError(f"seeds must align with islands: "
+                                 f"{len(seeds)} != {G}")
+            for g, props in enumerate(seeds):
+                Px0, Py0 = inits[g][0], inits[g][1]
+                for j, p in enumerate(props[:pop - 1]):
+                    Px0[j + 1] = p.Px
+                    Py0[j + 1] = p.Py
     hp = {
         "p_crossover": float(cfg.p_crossover),
         "p_mutate_partition": float(cfg.p_mutate_partition),
@@ -305,58 +310,60 @@ def solve_islands(
     n = len(tasks[0])
     X, Y = hws[0].X, hws[0].Y
     with x64():
-        consts_j = {k: jnp.asarray(v) for k, v in consts.items()}
-        win_j = {k: jnp.asarray(v) for k, v in win.items()}
-        f8 = lambda a: jnp.asarray(a, dtype=jnp.float64)
-        carry = (
-            f8(np.stack([i[0] for i in inits])),
-            f8(np.stack([i[1] for i in inits])),
-            f8(np.stack([i[2] for i in inits])),
-            f8(np.stack([i[3] for i in inits])),
-            jnp.full((G,), jnp.inf, dtype=jnp.float64),
-            jnp.zeros((G, n, X), dtype=jnp.float64),
-            jnp.zeros((G, n, Y), dtype=jnp.float64),
-            jnp.zeros((G, n), dtype=jnp.float64),
-            jnp.zeros((G, n), dtype=jnp.float64),
-            jnp.zeros((G,), dtype=jnp.int32),
-            jnp.zeros((G,), dtype=jnp.int32),
-        )
+        with span("ga.to_device") as sp:
+            genomes = [np.stack([i[j] for i in inits]) for j in range(4)]
+            sp.set_metadata(bytes=_host_bytes(consts, win, genomes))
+            consts_j = {k: jnp.asarray(v) for k, v in consts.items()}
+            win_j = {k: jnp.asarray(v) for k, v in win.items()}
+            f8 = lambda a: jnp.asarray(a, dtype=jnp.float64)
+            carry = (
+                *(f8(a) for a in genomes),
+                jnp.full((G,), jnp.inf, dtype=jnp.float64),
+                jnp.zeros((G, n, X), dtype=jnp.float64),
+                jnp.zeros((G, n, Y), dtype=jnp.float64),
+                jnp.zeros((G, n), dtype=jnp.float64),
+                jnp.zeros((G, n), dtype=jnp.float64),
+                jnp.zeros((G,), dtype=jnp.int32),
+                jnp.zeros((G,), dtype=jnp.int32),
+            )
         key = random.PRNGKey(cfg.seed)
         best_hist = []
         gens_left = int(cfg.generations)
         chunk_len = max(1, min(int(cfg.patience), gens_left))
         while gens_left > 0:
             L = min(chunk_len, gens_left)
-            key, sub = random.split(key)
-            keys = random.split(sub, L)
-            carry, (yb, _yf) = fn(consts_j, win_j, hp, carry, keys)
-            best_hist.append(np.asarray(yb))            # [G, L]
-            gens_left -= L
-            # One device→host sync per chunk — the early-stop check.
-            if (np.asarray(carry[_FLAT]) >= cfg.patience).all():
+            with span("ga.chunk", generations=L):
+                key, sub = random.split(key)
+                keys = random.split(sub, L)
+                carry, (yb, _yf) = fn(consts_j, win_j, hp, carry, keys)
+                best_hist.append(np.asarray(yb))            # [G, L]
+                gens_left -= L
+                # One device→host sync per chunk — the early-stop check.
+                stop = (np.asarray(carry[_FLAT]) >= cfg.patience).all()
+            if stop:
                 break
 
+    with span("ga.results"):
         best_obj = np.asarray(carry[_BEST_OBJ])
         bPx, bPy, bco, brd = (np.asarray(carry[i]) for i in (5, 6, 7, 8))
         steps = np.asarray(carry[_STEPS])
-    best_all = np.concatenate(best_hist, axis=1)        # [G, T]
-
-    results = []
-    for g in range(G):
-        # steps[g] = generations actually evaluated; frozen tail steps of
-        # the last chunk repeat the final state and are dropped.
-        T = int(steps[g])
-        part = Partition(np.rint(bPx[g]).astype(np.int64),
-                         np.rint(bPy[g]).astype(np.int64),
-                         np.rint(bco[g]).astype(np.int64))
-        part.validate(tasks[g])
-        results.append(GAResult(
-            partition=part,
-            redist_mask=(brd[g] > 0.5) & evs[g].chain_valid,
-            objective=float(best_obj[g]),
-            history=best_all[g, :T].copy(),
-            evaluations=T * pop,
-        ))
+        best_all = np.concatenate(best_hist, axis=1)        # [G, T]
+        results = []
+        for g in range(G):
+            # steps[g] = generations actually evaluated; frozen tail steps
+            # of the last chunk repeat the final state and are dropped.
+            T = int(steps[g])
+            part = Partition(np.rint(bPx[g]).astype(np.int64),
+                             np.rint(bPy[g]).astype(np.int64),
+                             np.rint(bco[g]).astype(np.int64))
+            part.validate(tasks[g])
+            results.append(GAResult(
+                partition=part,
+                redist_mask=(brd[g] > 0.5) & evs[g].chain_valid,
+                objective=float(best_obj[g]),
+                history=best_all[g, :T].copy(),
+                evaluations=T * pop,
+            ))
     return results
 
 

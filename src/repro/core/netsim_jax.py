@@ -43,7 +43,8 @@ __all__ = ["waterfill_rates", "waterfill_times", "simulate_pull_batch"]
 
 def waterfill_rates(inc, cap, active):
     """Max-min fair rates (traced): ``inc [F, L]``, ``cap [L]``,
-    ``active [F]`` (float 0/1) → rates ``[F]``. Progressive filling via
+    ``active [F]`` (float 0/1) → ``(rates [F], fills)``, ``fills`` the
+    int32 count of filling iterations. Progressive filling via
     ``lax.while_loop`` — at least one link retires per iteration."""
     F = inc.shape[0]
 
@@ -51,11 +52,11 @@ def waterfill_rates(inc, cap, active):
         return unfixed @ inc                              # [L]
 
     def cond(state):
-        _, unfixed, _ = state
+        _, unfixed, _, _ = state
         return jnp.any(users_of(unfixed) > 0)
 
     def body(state):
-        residual, unfixed, rates = state
+        residual, unfixed, rates, fills = state
         users = users_of(unfixed)
         live = users > 0
         share = jnp.where(live, residual / jnp.where(live, users, 1.0),
@@ -67,11 +68,12 @@ def waterfill_rates(inc, cap, active):
         residual = jnp.maximum(
             residual - (newly.astype(inc.dtype) @ inc) * s, 0.0)
         unfixed = jnp.where(newly, 0.0, unfixed)
-        return residual, unfixed, rates
+        return residual, unfixed, rates, fills + 1
 
-    init = (cap, active.astype(inc.dtype), jnp.zeros(F, dtype=inc.dtype))
-    _, _, rates = lax.while_loop(cond, body, init)
-    return rates
+    init = (cap, active.astype(inc.dtype), jnp.zeros(F, dtype=inc.dtype),
+            jnp.asarray(0, dtype=jnp.int32))
+    _, _, rates, fills = lax.while_loop(cond, body, init)
+    return rates, fills
 
 
 def waterfill_times(cap, inc, message_bytes):
@@ -80,19 +82,24 @@ def waterfill_times(cap, inc, message_bytes):
     Line-for-line port of :func:`repro.core.netsim.simulate_flows`:
     each event solves the waterfilling fixed point, advances to the next
     completion, retires finished flows. Returns ``(latency, done [F],
-    link_bytes [L])``. Usable inside an outer jit/vmap (the evaluator's
-    flow mode vmaps it over the op axis)."""
+    link_bytes [L], events, fills)``: the last two are int32 counts of
+    the event loop's iterations and of the filling iterations summed over
+    its events, so a vmapped caller can compare each lane's work with the
+    batch's lockstep count (a lane whose flows are all empty counts 0 and
+    0). Usable inside an outer jit/vmap (the evaluator's flow mode vmaps
+    it over the op axis)."""
     F, L = inc.shape
     bytes0 = message_bytes.astype(inc.dtype)
 
     def cond(state):
-        bytes_left, _, _, _, it = state
+        bytes_left, _, _, _, it, _ = state
         return jnp.any(bytes_left > EPS_BYTES) & (it < MAX_EVENTS)
 
     def body(state):
-        bytes_left, t, done, link_bytes, it = state
+        bytes_left, t, done, link_bytes, it, fills = state
         active = bytes_left > EPS_BYTES
-        rates = waterfill_rates(inc, cap, active.astype(inc.dtype))
+        rates, n_fill = waterfill_rates(inc, cap,
+                                        active.astype(inc.dtype))
         pos = active & (rates > 0)
         dt = jnp.min(jnp.where(
             pos, bytes_left / jnp.where(pos, rates, 1.0), jnp.inf))
@@ -101,12 +108,14 @@ def waterfill_times(cap, inc, message_bytes):
         bytes_left = jnp.maximum(bytes_left - moved, 0.0)
         newly = active & (bytes_left <= EPS_BYTES)
         done = jnp.where(newly, t + dt, done)
-        return bytes_left, t + dt, done, link_bytes, it + 1
+        return bytes_left, t + dt, done, link_bytes, it + 1, fills + n_fill
 
+    zero = jnp.asarray(0, dtype=jnp.int32)
     init = (bytes0, jnp.asarray(0.0, dtype=inc.dtype),
             jnp.zeros(F, dtype=inc.dtype), jnp.zeros(L, dtype=inc.dtype),
-            jnp.asarray(0, dtype=jnp.int32))
-    bytes_left, t, done, link_bytes, _ = lax.while_loop(cond, body, init)
+            zero, zero)
+    bytes_left, t, done, link_bytes, events, fills = lax.while_loop(
+        cond, body, init)
     # Parity with the numpy reference's loud failure: a run that exits
     # with unfinished flows (event-guard hit, or a zero-rate stall whose
     # dt=inf poisoned the carry) must not report a silently truncated
@@ -114,7 +123,7 @@ def waterfill_times(cap, inc, message_bytes):
     bad = jnp.any(bytes_left > EPS_BYTES) | ~jnp.isfinite(t)
     nan = jnp.asarray(jnp.nan, dtype=inc.dtype)
     return (jnp.where(bad, nan, t), jnp.where(bad, nan, done),
-            jnp.where(bad, nan, link_bytes))
+            jnp.where(bad, nan, link_bytes), events, fills)
 
 
 @functools.lru_cache(maxsize=None)
@@ -123,7 +132,7 @@ def _batch_inner():
     it doubles as the shard_map target of the sharded sweep fabric
     (DESIGN.md §15)."""
     def one(cap, inc, msg):
-        t, done, link_bytes = waterfill_times(cap, inc, msg)
+        t, done, link_bytes, _, _ = waterfill_times(cap, inc, msg)
         return {"latency": t, "done": done, "link_bytes": link_bytes}
 
     return jax.vmap(one)

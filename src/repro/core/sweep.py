@@ -73,6 +73,7 @@ from typing import Any, Callable, Iterable, Sequence
 
 import numpy as np
 
+from ..runtime.spans import span
 from .evaluator import EvalOptions, Evaluator
 from .hw import HWConfig
 from .workload import Partition, Task, uniform_partition
@@ -215,6 +216,9 @@ def _point_fingerprint(pt: EvalPoint, backend: str) -> tuple:
 
 _CACHE: dict[tuple, dict[str, Any]] = {}
 _STATS = {"hits": 0, "misses": 0}
+#: Numbers the ``sweep.eval`` / ``sweep.solve`` spans (``call``), so the
+#: spans nested in one call can be told from the next call's.
+_CALLS = itertools.count(1)
 
 
 def _copy_record(rec: dict[str, Any]) -> dict[str, Any]:
@@ -339,6 +343,38 @@ def _checkpointed(points, ckpt: SweepCheckpointer, straggler, run_chunk):
     return out
 
 
+def _lookup(points, cache: bool, fingerprint, copy):
+    """The cache lookups of one sweep call: ``(records, todo, fps)``,
+    records filled for the hits, ``todo`` the indices left to compute;
+    ``fingerprint(i)`` is point ``i``'s cache key. Spanned as
+    ``sweep.lookup`` with the call's hits and misses."""
+    records: list = [None] * len(points)
+    todo: list[int] = []
+    fps: list[tuple | None] = [None] * len(points)
+    with span("sweep.lookup") as sp:
+        for i in range(len(points)):
+            if cache:
+                fp = fingerprint(i)
+                fps[i] = fp
+                hit = _CACHE.get(fp)
+                if hit is not None:
+                    _STATS["hits"] += 1
+                    records[i] = copy(hit)
+                    continue
+                _STATS["misses"] += 1
+            todo.append(i)
+        misses = len(todo) if cache else 0
+        sp.set_metadata(hits=len(points) - len(todo), misses=misses)
+    return records, todo, fps
+
+
+def _store(records, todo, fps, copy) -> None:
+    """Insert a call's computed records into the cache, by value."""
+    with span("sweep.records", records=len(todo)):
+        for i in todo:
+            _CACHE[fps[i]] = copy(records[i])
+
+
 def _record(point: EvalPoint, out: dict[str, np.ndarray], i: int | tuple
             ) -> dict[str, Any]:
     """Extract one point's scalars/arrays from a batched output dict."""
@@ -407,20 +443,15 @@ def eval_sweep(
             points, ckpt, straggler,
             lambda c: eval_sweep(c, backend=backend, cache=True,
                                  devices=devices))
-    records: list[dict[str, Any] | None] = [None] * len(points)
-    todo: list[int] = []
-    fps: list[tuple | None] = [None] * len(points)
-    for i, pt in enumerate(points):
-        if cache:
-            fp = _point_fingerprint(pt, backend)
-            fps[i] = fp
-            hit = _CACHE.get(fp)
-            if hit is not None:
-                _STATS["hits"] += 1
-                records[i] = _copy_record(hit)
-                continue
-            _STATS["misses"] += 1
-        todo.append(i)
+    with span("sweep.eval", call=next(_CALLS), points=len(points)):
+        return _eval_sweep(points, backend, cache, devices)
+
+
+def _eval_sweep(points, backend: str, cache: bool, devices):
+    """:func:`eval_sweep` without its checkpointing."""
+    records, todo, fps = _lookup(
+        points, cache, lambda i: _point_fingerprint(points[i], backend),
+        _copy_record)
 
     if todo and backend == "numpy":
         for i in todo:
@@ -436,34 +467,38 @@ def eval_sweep(
         # call per group.
         groups: dict[tuple, list[int]] = {}
         evs: dict[int, Evaluator] = {}
-        for i in todo:
-            pt = points[i]
-            ev = Evaluator(pt.task, pt.hw, pt.options, backend="jax")
-            evs[i] = ev
-            sig = (len(pt.task), pt.hw.X, pt.hw.Y, ev.top.n_entrances,
-                   pt.options.redistribution, pt.options.async_exec,
-                   pt.options.energy_mode, pt.options.congestion)
-            groups.setdefault(sig, []).append(i)
+        with span("sweep.group") as sp:
+            for i in todo:
+                pt = points[i]
+                ev = Evaluator(pt.task, pt.hw, pt.options, backend="jax")
+                evs[i] = ev
+                sig = (len(pt.task), pt.hw.X, pt.hw.Y, ev.top.n_entrances,
+                       pt.options.redistribution, pt.options.async_exec,
+                       pt.options.energy_mode, pt.options.congestion)
+                groups.setdefault(sig, []).append(i)
+            sp.set_metadata(groups=len(groups))
 
         for sig, idxs in groups.items():
-            consts = [evs[i].consts() for i in idxs]
-            stacked = {k: np.stack([c[k] for c in consts])
-                       for k in consts[0]}
-            genomes = [_genome(points[i], evs[i]) for i in idxs]
-            Px = np.stack([g[0] for g in genomes])[:, None]   # [G,1,n,X]
-            Py = np.stack([g[1] for g in genomes])[:, None]
-            co = np.stack([g[2] for g in genomes])[:, None]
-            rd = np.stack([g[3] for g in genomes])[:, None]
+            with span("sweep.consts", points=len(idxs)) as sp:
+                consts = [evs[i].consts() for i in idxs]
+                stacked = {k: np.stack([c[k] for c in consts])
+                           for k in consts[0]}
+                genomes = [_genome(points[i], evs[i]) for i in idxs]
+                # [G,1,n,X], [G,1,n,Y], [G,1,n], [G,1,n]
+                Px, Py, co, rd = (np.stack([g[j] for g in genomes])[:, None]
+                                  for j in range(4))
+                sp.set_metadata(bytes=sum(
+                    a.nbytes for a in (*stacked.values(), Px, Py, co, rd)))
             out = evaluator_jax.grid_evaluate(
                 stacked, points[idxs[0]].options, Px, Py, co, rd,
                 devices=(points[idxs[0]].options.devices
                          if devices is None else devices))
-            for g, i in enumerate(idxs):
-                records[i] = _record(points[i], out, (g, 0))
+            with span("sweep.records", records=len(idxs)):
+                for g, i in enumerate(idxs):
+                    records[i] = _record(points[i], out, (g, 0))
 
     if cache:
-        for i in todo:
-            _CACHE[fps[i]] = _copy_record(records[i])
+        _store(records, todo, fps, _copy_record)
     return records  # type: ignore[return-value]
 
 
@@ -526,20 +561,10 @@ def netsim_sweep(
             nets, ckpt, straggler,
             lambda c: netsim_sweep(c, message_bytes, backend=backend,
                                    cache=True, devices=devices))
-    records: list[dict[str, Any] | None] = [None] * len(nets)
-    todo: list[int] = []
-    fps: list[tuple | None] = [None] * len(nets)
-    for i, net in enumerate(nets):
-        if cache:
-            fp = _netsim_fingerprint(net, message_bytes, backend)
-            fps[i] = fp
-            hit = _CACHE.get(fp)
-            if hit is not None:
-                _STATS["hits"] += 1
-                records[i] = _copy_record(hit)
-                continue
-            _STATS["misses"] += 1
-        todo.append(i)
+    records, todo, fps = _lookup(
+        nets, cache,
+        lambda i: _netsim_fingerprint(nets[i], message_bytes, backend),
+        _copy_record)
 
     if todo and backend == "numpy":
         for i in todo:
@@ -568,8 +593,7 @@ def netsim_sweep(
                               "link_bytes": out["link_bytes"][g]}
 
     if cache:
-        for i in todo:
-            _CACHE[fps[i]] = _copy_record(records[i])
+        _store(records, todo, fps, _copy_record)
     return records  # type: ignore[return-value]
 
 
@@ -711,7 +735,7 @@ def solve_grid(
                          f"one of ('ga', 'miqp', 'cosearch', "
                          f"'multitenant')")
     from .evaluator import resolve_auto_backend
-    from .ga import GAConfig, run_ga
+    from .ga import GAConfig
 
     if cfg is None:
         cfg = GAConfig()
@@ -719,20 +743,21 @@ def solve_grid(
     if backend not in ("numpy", "jax"):
         raise ValueError(f"unknown backend {backend!r}; "
                          f"one of ('numpy', 'jax', 'auto')")
-    records: list = [None] * len(points)
-    todo: list[int] = []
-    fps: list[tuple | None] = [None] * len(points)
-    for i, pt in enumerate(points):
-        if cache:
-            fp = _solver_fingerprint(pt, "ga", backend, objective, cfg)
-            fps[i] = fp
-            hit = _CACHE.get(fp)
-            if hit is not None:
-                _STATS["hits"] += 1
-                records[i] = _copy_solver_record(hit)
-                continue
-            _STATS["misses"] += 1
-        todo.append(i)
+    with span("sweep.solve", call=next(_CALLS), points=len(points)):
+        return _solve_grid_ga(points, objective, cfg, backend, cache,
+                              devices)
+
+
+def _solve_grid_ga(points, objective: str, cfg, backend: str, cache: bool,
+                   devices) -> list:
+    """:func:`solve_grid` for ``method="ga"``, without its checkpointing."""
+    from .ga import run_ga
+
+    records, todo, fps = _lookup(
+        points, cache,
+        lambda i: _solver_fingerprint(points[i], "ga", backend, objective,
+                                      cfg),
+        _copy_solver_record)
 
     if todo and backend == "numpy":
         for i in todo:
@@ -743,11 +768,14 @@ def solve_grid(
         from . import ga_jax
 
         groups: dict[tuple, list[int]] = {}
-        for i in todo:
-            pt = points[i]
-            sig = (len(pt.task), pt.hw.X, pt.hw.Y,
-                   pt.hw.topology.n_entrances, _strip_devices(pt.options))
-            groups.setdefault(sig, []).append(i)
+        with span("sweep.group") as sp:
+            for i in todo:
+                pt = points[i]
+                sig = (len(pt.task), pt.hw.X, pt.hw.Y,
+                       pt.hw.topology.n_entrances,
+                       _strip_devices(pt.options))
+                groups.setdefault(sig, []).append(i)
+            sp.set_metadata(groups=len(groups))
         for sig, idxs in groups.items():
             outs = ga_jax.solve_islands(
                 [points[i].task for i in idxs],
@@ -758,8 +786,7 @@ def solve_grid(
                 records[i] = out
 
     if cache:
-        for i in todo:
-            _CACHE[fps[i]] = _copy_solver_record(records[i])
+        _store(records, todo, fps, _copy_solver_record)
     return records
 
 
@@ -826,22 +853,12 @@ def cosearch_sweep(
 
     norm_hws = [dataclasses.replace(pt.hw, diagonal_links=False)
                 for pt in points]
-    records: list = [None] * len(points)
-    todo: list[int] = []
-    fps: list[tuple | None] = [None] * len(points)
-    for i, pt in enumerate(points):
-        if cache:
-            fp = _solver_fingerprint(
-                dataclasses.replace(pt, hw=norm_hws[i]),
-                "cosearch", "jax", objective, cfg)
-            fps[i] = fp
-            hit = _CACHE.get(fp)
-            if hit is not None:
-                _STATS["hits"] += 1
-                records[i] = _copy_solver_record(hit)
-                continue
-            _STATS["misses"] += 1
-        todo.append(i)
+    records, todo, fps = _lookup(
+        points, cache,
+        lambda i: _solver_fingerprint(
+            dataclasses.replace(points[i], hw=norm_hws[i]),
+            "cosearch", "jax", objective, cfg),
+        _copy_solver_record)
 
     if todo:
         groups: dict[tuple, list[int]] = {}
@@ -860,8 +877,7 @@ def cosearch_sweep(
                 records[i] = out
 
     if cache:
-        for i in todo:
-            _CACHE[fps[i]] = _copy_solver_record(records[i])
+        _store(records, todo, fps, _copy_solver_record)
     return records
 
 
@@ -943,20 +959,11 @@ def multitenant_sweep(
             lambda c: multitenant_sweep(c, objective, cfg,
                                         backend=backend, cache=True,
                                         devices=devices))
-    records: list = [None] * len(points)
-    todo: list[int] = []
-    fps: list[tuple | None] = [None] * len(points)
-    for i, pt in enumerate(points):
-        if cache:
-            fp = _multitenant_fingerprint(pt, backend, objective, cfg)
-            fps[i] = fp
-            hit = _CACHE.get(fp)
-            if hit is not None:
-                _STATS["hits"] += 1
-                records[i] = _copy_solver_record(hit)
-                continue
-            _STATS["misses"] += 1
-        todo.append(i)
+    records, todo, fps = _lookup(
+        points, cache,
+        lambda i: _multitenant_fingerprint(points[i], backend, objective,
+                                           cfg),
+        _copy_solver_record)
 
     for i in todo:
         pt = points[i]
@@ -965,8 +972,7 @@ def multitenant_sweep(
             backend=backend, cache=cache, devices=devices)
 
     if cache:
-        for i in todo:
-            _CACHE[fps[i]] = _copy_solver_record(records[i])
+        _store(records, todo, fps, _copy_solver_record)
     return records
 
 
@@ -1063,20 +1069,9 @@ def pipeline_sweep(
     # Fingerprint the *resolved* config so auto-selected records share
     # the cache with their concrete equivalents (the §12 rule).
     cfg = dataclasses.replace(cfg, engine=engine, backend=backend)
-    records: list = [None] * len(points)
-    todo: list[int] = []
-    fps: list[tuple | None] = [None] * len(points)
-    for i, pt in enumerate(points):
-        if cache:
-            fp = _pipeline_fingerprint(pt, cfg)
-            fps[i] = fp
-            hit = _CACHE.get(fp)
-            if hit is not None:
-                _STATS["hits"] += 1
-                records[i] = _copy_solver_record(hit)
-                continue
-            _STATS["misses"] += 1
-        todo.append(i)
+    records, todo, fps = _lookup(
+        points, cache, lambda i: _pipeline_fingerprint(points[i], cfg),
+        _copy_solver_record)
 
     if todo and (engine != "vectorized" or backend == "numpy"):
         for i in todo:
@@ -1100,8 +1095,7 @@ def pipeline_sweep(
                     float(out["makespan"][g]), engine="vectorized")
 
     if cache:
-        for i in todo:
-            _CACHE[fps[i]] = _copy_solver_record(records[i])
+        _store(records, todo, fps, _copy_solver_record)
     return records
 
 
@@ -1126,20 +1120,11 @@ def _solve_grid_miqp(points, objective, cfg, backend, cache,
     # Fingerprint the *resolved* config so auto-selected records share
     # the cache with their concrete equivalents.
     cfg = _dc.replace(cfg, engine=engine, backend=backend)
-    records: list = [None] * len(points)
-    todo: list[int] = []
-    fps: list[tuple | None] = [None] * len(points)
-    for i, pt in enumerate(points):
-        if cache:
-            fp = _solver_fingerprint(pt, "miqp", backend, objective, cfg)
-            fps[i] = fp
-            hit = _CACHE.get(fp)
-            if hit is not None:
-                _STATS["hits"] += 1
-                records[i] = _copy_solver_record(hit)
-                continue
-            _STATS["misses"] += 1
-        todo.append(i)
+    records, todo, fps = _lookup(
+        points, cache,
+        lambda i: _solver_fingerprint(points[i], "miqp", backend, objective,
+                                      cfg),
+        _copy_solver_record)
 
     if todo and (engine == "milp" or backend == "numpy"):
         # milp cannot batch; the numpy lattice is the per-point reference.
@@ -1165,6 +1150,5 @@ def _solve_grid_miqp(points, objective, cfg, backend, cache,
                 records[i] = out
 
     if cache:
-        for i in todo:
-            _CACHE[fps[i]] = _copy_solver_record(records[i])
+        _store(records, todo, fps, _copy_solver_record)
     return records
