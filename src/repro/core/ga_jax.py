@@ -209,6 +209,15 @@ def _chunk_fn(elite: int, tournament: int, freeze_redist: bool,
                                 energy_mode, congestion))
 
 
+def _init_key(task: Task, hw: HWConfig) -> tuple:
+    """What :func:`repro.core.ga._random_population_vec` reads of an
+    island: the op count and per-op ``M``/``N`` (uniform partition and
+    Sec-6.2 window) and the grid and systolic array. Rates, links,
+    chiplet classes and memory do not enter the initial population."""
+    return (len(task), tuple((op.M, op.N) for op in task.ops),
+            hw.X, hw.Y, hw.R, hw.C)
+
+
 def solve_islands(
     tasks: Sequence[Task],
     hws: Sequence[HWConfig],
@@ -224,6 +233,13 @@ def solve_islands(
     Returns one :class:`repro.core.ga.GAResult` per island, aligned with
     the inputs.
 
+    The host initial population is built once per distinct
+    (task dims, grid) key among the islands (:func:`_init_key`) and
+    copied to every island with that key; islands that differ only in
+    rates or links (e.g. ``diagonal_links`` x ``bw_nop`` grids) share
+    one build. Results are unchanged: each island starts from exactly
+    the population a solo run would build.
+
     ``devices`` (default: ``cfg.devices``, DESIGN.md §15) shards the
     island axis across local devices: consts/window/carry shard, the
     hyperparams and the per-generation keys replicate (keys are shared
@@ -235,7 +251,7 @@ def solve_islands(
     ``g``'s population rows ``1..`` are overwritten with the given
     :class:`Partition` proposals (row 0 keeps the uniform baseline, so a
     seeded run can never start worse than a cold one). Collector /
-    redistribution genes of a seeded row keep row 0's values — seeds
+    redistribution genes of a seeded row keep the init's draws — seeds
     speak only to the partition lattice (e.g. the projected-gradient
     proposals of :func:`repro.core.cosearch.gradient_seeds`, DESIGN.md
     §16). ``seeds=None`` preserves the cold-start init bit-for-bit."""
@@ -265,21 +281,32 @@ def solve_islands(
             win["hi_y"].append(hi[:, 1])
         win = {k: np.stack(v).astype(np.float64) for k, v in win.items()}
         sp.set_metadata(bytes=_host_bytes(consts, win))
-    with span("ga.init", islands=G, population=pop):
+    with span("ga.init", islands=G, population=pop) as sp:
         # Shared host init (per-island RNG seeded by cfg.seed alone, so a
-        # point's result never depends on its position in the grid).
-        inits = [_random_population_vec(np.random.default_rng(cfg.seed),
-                                        t, h, cfg, pop)
-                 for t, h in zip(tasks, hws)]
+        # point's result never depends on its position in the grid). The
+        # population depends on the island only through _init_key, so it
+        # is built once per distinct key and shared by reference; np.stack
+        # below copies it per island.
+        built = {}
+        inits = []
+        for t, h in zip(tasks, hws):
+            k = _init_key(t, h)
+            if k not in built:
+                built[k] = _random_population_vec(
+                    np.random.default_rng(cfg.seed), t, h, cfg, pop)
+            inits.append(built[k])
+        sp.set_metadata(distinct=len(built))
         if seeds is not None:
             if len(seeds) != G:
                 raise ValueError(f"seeds must align with islands: "
                                  f"{len(seeds)} != {G}")
             for g, props in enumerate(seeds):
-                Px0, Py0 = inits[g][0], inits[g][1]
+                # own copies: a build is shared by every island of its key
+                Px0, Py0 = inits[g][0].copy(), inits[g][1].copy()
                 for j, p in enumerate(props[:pop - 1]):
                     Px0[j + 1] = p.Px
                     Py0[j + 1] = p.Py
+                inits[g] = (Px0, Py0, *inits[g][2:])
     hp = {
         "p_crossover": float(cfg.p_crossover),
         "p_mutate_partition": float(cfg.p_mutate_partition),

@@ -286,6 +286,118 @@ def test_solve_grid_numpy_backend(_fresh_cache):
     np.testing.assert_array_equal(rec.partition.Px, ref.partition.Px)
 
 
+# ------------------------------------------- island initial populations
+_RESULT_FIELDS = ("partition", "redist_mask", "objective", "history",
+                  "evaluations")
+
+
+def _assert_same_result(a, b):
+    """Bitwise equality of two GAResults, field by field."""
+    for f in _RESULT_FIELDS:
+        x, y = getattr(a, f), getattr(b, f)
+        if f == "partition":
+            for k in ("Px", "Py", "collectors"):
+                u, v = getattr(x, k), getattr(y, k)
+                assert u.dtype == v.dtype and u.tobytes() == v.tobytes(), k
+        elif isinstance(x, np.ndarray):
+            assert x.dtype == y.dtype and x.tobytes() == y.tobytes(), f
+        else:
+            assert x == y, f
+
+
+def _recorded_builds(monkeypatch):
+    """The host initial populations solve_islands builds, as built."""
+    from repro.core import ga
+
+    builds = []
+    real = ga._random_population_vec
+
+    def keep(rng, task, hw, cfg, pop):
+        builds.append(real(rng, task, hw, cfg, pop))
+        return builds[-1]
+
+    monkeypatch.setattr(ga, "_random_population_vec", keep)
+    return builds
+
+
+# AlexNet on the 4x4 type-A package under the latency objective: the
+# search moves off the uniform row within these few generations, so the
+# results depend on every row of the initial population.
+ISLAND_CFG = GAConfig(generations=8, population=16, patience=4, seed=4,
+                      p_mutate_partition=0.1)
+ISLAND_HWS = [make_hw("A", 4, "hbm", diagonal_links=d, bw_nop=b)
+              for d, b in ((False, 15e9), (True, 30e9), (False, 60e9),
+                           (True, 120e9))]
+
+
+@pytest.mark.parametrize("batches,distinct", [
+    # one task on one grid: every island shares one build
+    ((1, 1, 1, 1), 1),
+    # same op count, different M: each task gets its own population
+    ((1, 2, 1, 2), 2),
+])
+def test_islands_share_init_bitwise(monkeypatch, batches, distinct):
+    """Islands that differ only in links and NoP bandwidth share one host
+    initial population, and each island's result is bitwise the result of
+    the same island solved alone."""
+    from repro.core.ga_jax import run_ga_jax, solve_islands
+    from repro.graphs import WORKLOADS
+
+    ts = [WORKLOADS["alexnet"](batch=b) for b in batches]
+    solo = [run_ga_jax(t, h, "latency", OPTS, ISLAND_CFG)
+            for t, h in zip(ts, ISLAND_HWS)]
+    builds = _recorded_builds(monkeypatch)
+    recs = solve_islands(ts, ISLAND_HWS, OPTS, "latency", ISLAND_CFG)
+    assert len(builds) == distinct
+    for rec, ref in zip(recs, solo):
+        _assert_same_result(rec, ref)
+
+
+def test_island_seeds_do_not_leak_through_shared_init(monkeypatch):
+    """Two islands with one init key, only island 0 seeded: island 1 must
+    read its unseeded solo result, and island 0 its seeded solo result,
+    although both start from one shared build, which stays as built."""
+    from repro.core import ga
+    from repro.core.ga_jax import run_ga_jax, solve_islands
+    from repro.graphs import WORKLOADS
+
+    task = WORKLOADS["alexnet"](batch=1)
+    hws = [ISLAND_HWS[1], ISLAND_HWS[0]]
+    # single unit moves off the uniform row, each of which shortens its
+    # latency on hws[0]; the 15 proposals combine four to seven of them
+    moves = [(5, "Py", 0, 1), (6, "Py", 0, 1), (7, "Py", 0, 1),
+             (3, "Px", 3, 0), (2, "Px", 3, 0), (3, "Py", 0, 3),
+             (1, "Px", 3, 0)]
+    props = []
+    for mask in range(2 ** len(moves) - 1, 2 ** len(moves) - 16, -1):
+        p = uniform_partition(task, 4, 4)
+        for k, (i, axis, src, dst) in enumerate(moves):
+            if mask >> k & 1:
+                getattr(p, axis)[i, src] -= 16
+                getattr(p, axis)[i, dst] += 16
+        p.validate(task)
+        props.append(p)
+    seeded = [solve_islands([task], [h], OPTS, "latency", ISLAND_CFG,
+                            seeds=[props])[0] for h in hws]
+    cold = [run_ga_jax(task, h, "latency", OPTS, ISLAND_CFG) for h in hws]
+    # the seeds change the search on both packages, so a seed row
+    # reaching island 1 would show in its result
+    for a, b in zip(seeded, cold):
+        assert a.history.tobytes() != b.history.tobytes()
+
+    fresh = ga._random_population_vec(
+        np.random.default_rng(ISLAND_CFG.seed), task, hws[0], ISLAND_CFG,
+        ISLAND_CFG.population)
+    builds = _recorded_builds(monkeypatch)
+    recs = solve_islands([task, task], hws, OPTS, "latency", ISLAND_CFG,
+                         seeds=[props, []])
+    _assert_same_result(recs[0], seeded[0])
+    _assert_same_result(recs[1], cold[1])
+    built, = builds
+    for a, b in zip(built, fresh):
+        np.testing.assert_array_equal(a, b)
+
+
 # ---------------------------------------------- operator-level properties
 @given(seed=st.integers(min_value=0, max_value=2**31 - 1),
        X=st.sampled_from([2, 4, 6]),

@@ -34,7 +34,7 @@ SOLVE_SPANS = {
     "sweep.lookup": {"hits", "misses"},
     "sweep.group": {"groups"},
     "ga.consts": {"islands", "bytes"},
-    "ga.init": {"islands", "population"},
+    "ga.init": {"islands", "population", "distinct"},
     "ga.to_device": {"bytes"},
     "ga.chunk": {"generations"},
     "ga.results": set(),
@@ -186,7 +186,9 @@ def test_solve_grid_spans(tmp_path):
     assert set(call) == set(SOLVE_SPANS)
     assert call["sweep.solve"][0]["points"] == 2
     assert call["sweep.lookup"] == [{"hits": 0, "misses": 2}]
-    assert call["ga.init"] == [{"islands": 2, "population": 8}]
+    # one task on one grid: both islands share one initial population
+    assert call["ga.init"] == [{"islands": 2, "population": 8,
+                                "distinct": 1}]
     consts, = call["ga.consts"]
     assert consts["islands"] == 2 and consts["bytes"] > 0
     # constants, windows and the initial genomes go to the device
