@@ -1,18 +1,23 @@
 """The comparison that decides ``correct``.
 
 Every answer the timed path produced (or a sample drawn from the seed)
-is compared, after the window, with the plain reference in
-``bench.reference.mcm``, by the check of the call's kind
-(``bench/kinds``). Each comparison gives one number, held against a
-limit that the traffic file states; the run is correct when every
-number is at or under its limit. ``rel_err`` comes from the
-repository's chip smoke run, unchanged in meaning.
+is compared, after the window, with the configuration's plain reference
+(:func:`reference_of`, interface in ``bench/reference/__init__.py``), by
+the check of the call's kind (``bench/kinds``). Each comparison gives one
+number, held against a limit that the traffic file states; the run is
+correct when every number is at or under its limit. ``rel_err`` comes
+from the repository's chip smoke run, unchanged in meaning.
 """
 from __future__ import annotations
 
+from pathlib import Path
+
 import numpy as np
 
-from bench.reference import mcm
+from bench.harness import manifest
+
+#: The reference a configuration without a ``reference`` key names.
+DEFAULT_REFERENCE = "mcm"
 
 #: Record fields that the evaluator's answer consists of.
 EVAL_KEYS = ("latency", "energy", "edp", "t_in", "t_comp", "t_out",
@@ -56,23 +61,34 @@ class Checks:
                 for k, v in self.values.items()]
 
 
+def reference_of(cfg: dict, root: Path):
+    """The plain reference module of a configuration: the file
+    ``bench/reference/<name>.py`` under ``root``, ``<name>`` being the
+    configuration's ``reference`` key, or ``mcm`` without one. The only
+    place that maps a configuration to its reference."""
+    return manifest.module(root, "reference",
+                           cfg.get("reference", DEFAULT_REFERENCE))
+
+
 class Reference:
     """The plain reference for one configuration, one scorer per
-    (package variant, congestion model)."""
+    (package variant, congestion model); ``root`` is the checkout whose
+    ``bench/reference`` holds it."""
 
-    def __init__(self, cfg: dict):
+    def __init__(self, cfg: dict, root: Path):
         self.cfg = cfg
-        self.ops = mcm.graph_ops(cfg["workload"])
-        self._refs: dict[tuple, mcm.Reference] = {}
+        self.module = reference_of(cfg, root)
+        self.ops = self.module.graph_ops(cfg["workload"])
+        self._refs: dict[tuple, object] = {}
 
     def scorer(self, variant: dict | None = None,
-               congestion: str | None = None) -> mcm.Reference:
+               congestion: str | None = None):
         variant = variant or {}
         opts = dict(self.cfg["options"])
         if congestion is not None:
             opts["congestion"] = congestion
         key = (tuple(sorted(variant.items())), opts["congestion"])
         if key not in self._refs:
-            self._refs[key] = mcm.Reference(
-                self.ops, mcm.package(self.cfg, **variant), opts)
+            self._refs[key] = self.module.Reference(
+                self.ops, self.module.package(self.cfg, **variant), opts)
         return self._refs[key]

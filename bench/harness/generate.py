@@ -11,8 +11,6 @@ import itertools
 
 import numpy as np
 
-from bench.reference import mcm
-
 
 def rng(seed: int, *stream: int) -> np.random.Generator:
     """An independent stream per (seed, *stream): seeds may exceed 32 bits."""

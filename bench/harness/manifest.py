@@ -1,6 +1,9 @@
 """Finds a cell's parts by name from ``BENCHMARK.json``.
 
 * a configuration is the file its entry names, ``bench/configs/<name>.json``;
+* a plain reference is the module ``bench/reference/<name>.py`` that the
+  configuration's ``reference`` key names, ``mcm`` without it
+  (``bench.harness.check.reference_of``; see ``bench/reference/__init__.py``);
 * a traffic mix is ``bench/traffic/<name>.json``, a file of parameters
   that names its ``kind`` and its ``driver``;
 * a kind is the module ``bench/kinds/<kind>.py``: what one call of that
@@ -18,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 
@@ -78,7 +82,8 @@ _MODULES: dict[Path, object] = {}
 
 def module(root: Path, subdir: str, name: str):
     """The module ``bench/<subdir>/<name>.py`` under ``root``, loaded once
-    from its file (names may hold dots, which import paths cannot)."""
+    from its file (names may hold dots, which import paths cannot) and
+    entered in ``sys.modules``, which dataclasses look up."""
     path = (Path(root) / "bench" / subdir / f"{name}.py").resolve()
     if path not in _MODULES:
         if not path.is_file():
@@ -87,6 +92,7 @@ def module(root: Path, subdir: str, name: str):
             subdir, name.replace(".", "_").replace("-", "_"))
         spec = importlib.util.spec_from_file_location(mod_name, path)
         mod = importlib.util.module_from_spec(spec)
+        sys.modules[mod_name] = mod
         spec.loader.exec_module(mod)
         _MODULES[path] = mod
     return _MODULES[path]

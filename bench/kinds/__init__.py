@@ -6,7 +6,9 @@ A kind module has five functions:
 * ``call(traffic, cfg, seed, i) -> dict`` -- the ``i``-th call of the mix
   as plain data (numpy and built-ins only), with ``designs``, the work it
   asks for, counted from the traffic file and never from what the program
-  reports. The same arguments always give the same call.
+  reports. The same arguments always give the same call. A generator
+  that needs the graph or the package takes them from the configuration's
+  reference (``bench.harness.check.reference_of``).
 * ``warm_calls(traffic, cfg, seed) -> list[dict]`` -- calls that reach
   every executable the window will run, on inputs the window never sends.
 * ``prepare(system, call)`` -- the program's arguments for ``call``,

@@ -8,16 +8,23 @@ seed, with the reference's, over every field of the record.
 """
 from __future__ import annotations
 
+from pathlib import Path
+
 from bench.harness import generate as gen
-from bench.harness.check import EVAL_KEYS, rel_err
+from bench.harness.check import EVAL_KEYS, reference_of, rel_err
 from bench.harness.sut import plain
-from bench.reference import mcm
+
+#: The checkout this kind was loaded from; its ``bench/reference`` holds
+#: the configuration's reference.
+ROOT = Path(__file__).resolve().parents[2]
 
 
 def call(traffic: dict, cfg: dict, seed: int, i: int) -> dict:
-    ops = mcm.graph_ops(cfg["workload"])
-    Px, Py, co = gen.partitions(gen.rng(seed, 1, i), ops, mcm.package(cfg),
-                                traffic["points"], traffic.get("steps", 2))
+    ref = reference_of(cfg, ROOT)
+    Px, Py, co = gen.partitions(gen.rng(seed, 1, i),
+                                ref.graph_ops(cfg["workload"]),
+                                ref.package(cfg), traffic["points"],
+                                traffic.get("steps", 2))
     return {"congestion": traffic["congestion"], "Px": Px, "Py": Py,
             "collectors": co, "designs": traffic["points"]}
 

@@ -123,8 +123,8 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, devs,
     checks.count("calls_failed", 0)
     for _ in range(w.failed):
         checks.count("calls_failed", True)
-    cell.kind.check(checks, check.Reference(cell.config), cell.traffic, w,
-                    seed)
+    cell.kind.check(checks, check.Reference(cell.config, cell.root),
+                    cell.traffic, w, seed)
 
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": int(peak)}

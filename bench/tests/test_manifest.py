@@ -170,3 +170,111 @@ def test_adding_a_traffic_kind_is_adding_files(tmp_path):
     assert out["attempted"] >= 1 and out["failed"] == 0
     assert set(out["metrics"]) == {"designs_per_s", "setup_s"}
     assert set(out["checks"]) == {"calls_failed", "echo_wrong"}
+
+
+#: A plain reference that no existing file knows: a three-op chain whose
+#: graph name ``mcm.GRAPHS`` lacks, on ``mcm``'s package and scorer; its
+#: ``evaluate`` scales the latency by ``SCALE``.
+CHAIN_REF = """
+from bench.reference import mcm
+from bench.reference.mcm import package  # noqa: F401
+
+SCALE = {scale!r}
+
+
+def graph_ops(workload):
+    m, k = workload["m"], workload["k"]
+    return [mcm.Op("in", m, k, k, epilogue=1),
+            mcm.Op("mid", m, k, k, sync=True, chained=True),
+            mcm.Op("out", m, k, 10, chained=True)]
+
+
+class Reference(mcm.Reference):
+    def evaluate(self, *args):
+        out = super().evaluate(*args)
+        return dict(out, latency=out["latency"] * SCALE)
+"""
+
+
+def _chain_task(m, k):
+    """The program's side of ``CHAIN_REF``'s graph."""
+    from repro.core.workload import GemmOp, Task
+
+    return Task("chain3", [
+        GemmOp("in", M=m, K=k, N=k, epilogue_flops_per_elem=1),
+        GemmOp("mid", M=m, K=k, N=k, sync=True, chained=True),
+        GemmOp("out", M=m, K=k, N=10, chained=True)])
+
+
+def _architecture(tmp_path, monkeypatch, scale=1.0):
+    """A checkout in ``tmp_path`` with a new architecture added as files: its
+    reference module, its configuration naming it and a small flow-eval
+    cell; the program's graph is patched in as a later PR would add it."""
+    import repro.graphs
+
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "bench/reference/chain3.py").write_text(
+        CHAIN_REF.format(scale=scale))
+    cfg = json.loads((ROOT / "bench/tests/data/alexnet.a4x4_hbm.json")
+                     .read_text())
+    cfg.update(name="chain3.a4x4_hbm", reference="chain3",
+               workload={"graph": "chain3", "m": 96, "k": 48})
+    (tmp_path / "bench/configs/chain3.a4x4_hbm.json").write_text(
+        json.dumps(cfg))
+    t = json.loads((ROOT / "bench/traffic/flow_eval.json").read_text())
+    t["points"], t["check"]["sample"] = 6, 4
+    (tmp_path / "bench/traffic/flow_tiny.json").write_text(json.dumps(t))
+    m = json.loads((ROOT / "BENCHMARK.json").read_text())
+    m["configs"].append({"name": "chain3.a4x4_hbm", "source": "x",
+                         "file": "bench/configs/chain3.a4x4_hbm.json",
+                         "reduced": [], "why": "x"})
+    m["workloads"].append({"name": "chain3.flow_tiny",
+                           "config": "chain3.a4x4_hbm",
+                           "traffic": "flow_tiny", "chips": 1, "why": "x"})
+    m["end_to_end"][0]["workloads"].append("chain3.flow_tiny")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(m))
+    monkeypatch.setitem(repro.graphs.WORKLOADS, "chain3", _chain_task)
+    return manifest.cell(tmp_path, "chain3.flow_tiny")
+
+
+@pytest.mark.parametrize("scale,correct", [(1.0, True), (1.0 + 1e-6, False)],
+                         ids=["exact", "latency_skewed"])
+def test_adding_an_architecture_is_adding_files(tmp_path, monkeypatch, scale,
+                                                correct):
+    """A graph that no existing reference knows is generated, run and
+    checked through the reference its configuration names, and that
+    reference alone decides ``correct``."""
+    import warnings
+
+    import jax
+
+    from bench import run
+    from bench.reference import mcm
+
+    assert "chain3" not in mcm.GRAPHS
+    cell = _architecture(tmp_path, monkeypatch, scale)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        out = run.run_cell(cell, 2**31 + 29, 0.05, False, jax.devices())
+    assert out["attempted"] >= 1 and out["failed"] == 0
+    assert out["correct"] is correct, out["checks"]
+    err = out["checks"]["eval_flow_rel_err"]["value"]
+    assert (err < 1e-9) if correct else (err > 1e-7), err
+
+
+@pytest.mark.parametrize("named", [False, True], ids=["default", "named"])
+def test_reference_found_by_name(tmp_path, monkeypatch, named):
+    """The configuration's ``reference`` key names the module; without it
+    the module is ``mcm``."""
+    from bench.harness import check
+
+    cell = _architecture(tmp_path, monkeypatch)
+    cfg = cell.config if named else json.loads(
+        (ROOT / "bench/tests/data/alexnet.a4x4_hbm.json").read_text())
+    assert ("reference" in cfg) is named
+    mod = check.reference_of(cfg, tmp_path)
+    want = "chain3" if named else check.DEFAULT_REFERENCE
+    assert mod.__file__ == str(tmp_path.resolve() / "bench/reference"
+                               / f"{want}.py")
+    assert check.Reference(cfg, tmp_path).module is mod
