@@ -24,7 +24,7 @@ from repro.core import (CoSearchConfig, EvalOptions, make_hw,
                         refine_schedule, sweep)
 from repro.core.miqp import MIQPConfig
 from repro.core.sweep import PipelinePoint
-from repro.graphs import WORKLOADS
+from repro.graphs import PAPER_WORKLOADS, WORKLOADS
 
 from .common import emit, geomean, save_json
 
@@ -40,7 +40,7 @@ BATCH = 4
 
 def main(fast: bool = False, backend: str = "jax"):
     hw = make_hw("A", 4, "dram")
-    wnames = ("alexnet", "hydranet") if fast else tuple(WORKLOADS)
+    wnames = ("alexnet", "hydranet") if fast else PAPER_WORKLOADS
     tasks = {w: WORKLOADS[w](batch=1) for w in wnames}
     opts = EvalOptions(redistribution=True, async_exec=True)
 

@@ -19,7 +19,7 @@ from __future__ import annotations
 from repro.core import EvalOptions, make_hw, optimize, sweep
 from repro.core.ga import GAConfig
 from repro.core.miqp import MIQPConfig
-from repro.graphs import WORKLOADS
+from repro.graphs import PAPER_WORKLOADS, WORKLOADS
 
 from .common import emit, geomean, save_json
 
@@ -31,7 +31,7 @@ METHOD_KW = {"simba": {},
 
 
 def main(fast: bool = False, backend: str = "jax"):
-    workloads = {k: fn(batch=1) for k, fn in WORKLOADS.items()}
+    workloads = {k: WORKLOADS[k](batch=1) for k in PAPER_WORKLOADS}
     if fast:
         workloads = {k: workloads[k] for k in ("alexnet", "hydranet")}
     hws = {t: make_hw(t, 4, "hbm") for t in "ABCD"}

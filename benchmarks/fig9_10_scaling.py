@@ -22,7 +22,7 @@ import time
 from repro.core import (EvalOptions, make_hw, refine_schedule, sweep)
 from repro.core.ga import GAConfig
 from repro.core.miqp import MIQPConfig
-from repro.graphs import WORKLOADS
+from repro.graphs import PAPER_WORKLOADS, WORKLOADS
 
 from .common import emit, geomean, save_json
 
@@ -37,7 +37,7 @@ MIQP_SOLVE_OPTS = EvalOptions(redistribution=True, async_exec=False)
 
 def main(fast: bool = False, backend: str = "jax"):
     grids = (4, 8) if fast else (4, 8, 16)
-    wnames = ("alexnet", "hydranet") if fast else tuple(WORKLOADS)
+    wnames = ("alexnet", "hydranet") if fast else PAPER_WORKLOADS
     tasks = {w: WORKLOADS[w](batch=1) for w in wnames}
     hws = {g: make_hw("A", g, "hbm") for g in grids}
 

@@ -477,6 +477,11 @@ def _consts_pair(task: Task, hw: HWConfig, options: EvalOptions):
     """(plain, diagonal) constant bundles + the plain Evaluator. Raises
     if the two meshes diverge outside DIAG_KEYS — the diag gene's
     select/interpolate contract."""
+    if task.group_shape[0]:
+        raise ValueError(
+            f"co-search cannot take uneven grouped GEMMs ({task.name}): "
+            f"its gradient seeding relaxes tile counts that the grouped "
+            f"term computes per group; use solve_grid(method='ga')")
     hw_p, hw_d = _hw_pair(hw)
     evp = Evaluator(task, hw_p, options, backend="numpy")
     evd = Evaluator(task, hw_d, options, backend="numpy")

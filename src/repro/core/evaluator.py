@@ -27,6 +27,13 @@ Modeling conventions (documented in DESIGN.md §5):
     with the hop matrices of eqs. 10–12 (+ the diagonal-link alternative
     of Sec. 5.1.1 taken as a per-chiplet min).
   * Collection (eq. 8) = non-entrance group bytes / (entrance_links × BW).
+  * Uneven grouped GEMMs (ops with ``group_sizes``, DESIGN.md §5): chiplet
+    row x holds rows [S_x, S_x + Px[x]) of the group-ordered rows, so it
+    needs the weights of the t_x groups it covers (weight bytes
+    Py[y]·K·B·t_x at chiplet (x, y)) and pads its systolic tiles per
+    group (Σ_g ceil(r_{x,g}/R) row tiles). Off-chip, an entrance fetches
+    each group's column stripe once for the rows it serves, multicast
+    as every op's weights are.
 """
 from __future__ import annotations
 
@@ -38,7 +45,7 @@ from .hw import HWConfig
 from .workload import Partition, Task
 
 __all__ = ["CONGESTION_MODES", "DEVICE_MODES", "EvalOptions", "EvalResult",
-           "Evaluator"]
+           "Evaluator", "group_rows"]
 
 
 #: Congestion models for the communication phases (DESIGN.md §11):
@@ -98,6 +105,18 @@ class EvalResult:
              float(self.t_out[i]))
             for i in range(len(self.t_in))
         ]
+
+
+def group_rows(Pg: np.ndarray, off: np.ndarray) -> np.ndarray:
+    """``r[..., j, x, g]``: rows of group g that chiplet row x holds in
+    grouped op j. ``Pg`` [..., ng, X] are the grouped ops' row shares,
+    ``off`` [ng, G+1] their group offsets (``Task.arrays()["g_off"]``);
+    chiplet row x holds rows [S_x, S_x + Pg[x]), S the exclusive
+    cumulative sum of ``Pg``."""
+    hi = np.cumsum(Pg, axis=-1)
+    lo = hi - Pg
+    return np.maximum(np.minimum(hi[..., None], off[:, None, 1:])
+                      - np.maximum(lo[..., None], off[:, None, :-1]), 0.0)
 
 
 def _ceil_div(a, b):
@@ -165,6 +184,7 @@ class Evaluator:
         self.sync = arr["sync"].astype(bool)
         self.w_scale = arr["w_scale"].astype(np.float64)
         self.epilogue = arr["epilogue"].astype(np.float64)
+        self.g_idx, self.g_off = arr["g_idx"], arr["g_off"]
 
         # chain_valid[i]: redistribution after op i is semantically legal —
         # op i+1 consumes op i's output as its activation. Dims need not
@@ -288,11 +308,16 @@ class Evaluator:
         # the paper's LS data-duplication overhead shows up here).
         A_e = np.einsum("ex,pnx->pne", self.row_mask, inA)
         W_e = np.einsum("ey,pny->pne", self.col_mask, inW)
+        inW_xy = inW[:, :, None, :]                  # weight bytes per chiplet
+        grouped = self._group_terms(Px) if len(self.g_idx) else None
+        if grouped is not None:
+            inW_xy = inW_xy * grouped["rows"][..., None]          # [P,n,X,Y]
+            W_e = W_e * grouped["fetch"]
         t_off_in = ((keepA[..., None] * A_e + W_e) / bw_ent).max(axis=-1)
 
         # NoP distribution: per-chiplet received bytes × hops / BW.
         tA_xy = inA[:, :, :, None] * self.hA[None, None]          # bytes*hops
-        tW_xy = inW[:, :, None, :] * self.hW[None, None]
+        tW_xy = inW_xy * self.hW[None, None]
 
         flow_mode = self.opts.congestion == "flow"
         if flow_mode:
@@ -302,7 +327,7 @@ class Evaluator:
             # term (shared stripes are fetched once per group — simulating
             # the sole-user port would just re-derive t_off_in).
             demand = (keepA[..., None, None] * inA[:, :, :, None]
-                      + inW[:, :, None, :])                      # [P,n,X,Y]
+                      + inW_xy)                                  # [P,n,X,Y]
             dist_done, t_coll_flow = self._flow_times(demand, chunk)
             nop_in_xy = None          # regime-only (tA/tW still feed energy)
             t_in = np.maximum(t_off_in, dist_done.max(axis=(-1, -2)))
@@ -314,7 +339,8 @@ class Evaluator:
         # ------------------------------------------------ phase 2: compute
         # SCALE-Sim output-stationary latency (eq. 7) + SIMD epilogue.
         fill = (2.0 * R + C + K - 2.0)[None, :, None, None]
-        tiles = np.ceil(Px / R)[:, :, :, None] * np.ceil(Py / C)[:, :, None, :]
+        row_tiles = np.ceil(Px / R) if grouped is None else grouped["tiles"]
+        tiles = row_tiles[:, :, :, None] * np.ceil(Py / C)[:, :, None, :]
         cyc = fill * tiles
         cyc = cyc + (self.epilogue[None, :, None, None]
                      * Px[:, :, :, None] * Py[:, :, None, :] / C)
@@ -399,7 +425,8 @@ class Evaluator:
         e_nop = hw.e_nop_bit_hop * 8.0
         e_mac = hw.e_mac_cycle
 
-        sram_bytes = (Y * inA.sum(axis=-1) + X * inW.sum(axis=-1)
+        w_held = X if grouped is None else grouped["held"]  # rows holding W
+        sram_bytes = (Y * inA.sum(axis=-1) + w_held * inW.sum(axis=-1)
                       + chunk.sum(axis=(-1, -2)))
         E_sram = e_sram * sram_bytes.sum(axis=1)
 
@@ -439,6 +466,30 @@ class Evaluator:
         }
 
     # -------------------------------------------------------------- helpers
+    def _group_terms(self, Px: np.ndarray) -> dict[str, np.ndarray]:
+        """Per-op factors of the grouped-GEMM term, 1 (or today's value)
+        for ops without groups: ``rows`` [P,n,X] groups whose weights
+        chiplet row x needs (t_x), ``fetch`` [P,n,E] groups each entrance
+        fetches for the rows it serves, ``held`` [P,n] Σ_x t_x, ``tiles``
+        [P,n,X] row tiles padded per group."""
+        gi = self.g_idx
+        r = group_rows(Px[:, gi], self.g_off)                   # [P,ng,X,G]
+        has = r > 0
+        t = has.sum(axis=-1).astype(np.float64)
+        P, n, X = Px.shape
+        rows = np.ones_like(Px)
+        rows[:, gi] = t
+        fetch = np.ones((P, n, self.row_mask.shape[0]))
+        fetch[:, gi] = (np.einsum("ex,pjxg->pjeg",
+                                  self.row_mask.astype(np.float64),
+                                  has.astype(np.float64))
+                        > 0).sum(axis=-1)
+        held = np.full((P, n), float(X))
+        held[:, gi] = t.sum(axis=-1)
+        tiles = np.ceil(Px / float(self.hw.R))
+        tiles[:, gi] = np.ceil(r / float(self.hw.R)).sum(axis=-1)
+        return {"rows": rows, "fetch": fetch, "held": held, "tiles": tiles}
+
     def _flow_times(self, demand: np.ndarray, chunk: np.ndarray
                     ) -> tuple[np.ndarray, np.ndarray]:
         """Simulate the distribution and collection phases per

@@ -75,6 +75,13 @@ CONST_KEYS = (
     "e_sram", "e_mem", "e_nop", "e_mac",
 )
 
+#: Group tables of a task with uneven grouped GEMMs, present only then
+#: (so a task without them traces the same program as before they
+#: existed): ``g_idx`` [ng] int32 the grouped ops, ``g_map`` [n] int32
+#: 1 + j for grouped op j and 0 elsewhere, ``g_off`` [ng, G+1] their
+#: group row offsets.
+GROUP_KEYS = ("g_idx", "g_map", "g_off")
+
 #: Per-lane work counts of the flow netsim's two call sites (``dist``:
 #: the input-load flows, ``coll``: the collection flows), [n] per
 #: candidate in ``congestion="flow"`` mode: event-loop iterations and
@@ -100,7 +107,13 @@ def consts_from_evaluator(ev) -> EvalConsts:
         # consts are stacked per sweep point and moved to device, and
         # XLA's dead-code elimination cannot recover that traffic.
         flow_cap = dist_inc = coll_inc = np.zeros(1)
-    return {
+    groups = {}
+    if len(ev.g_idx):
+        g_map = np.zeros(len(ev.M), dtype=np.int32)
+        g_map[ev.g_idx] = np.arange(1, len(ev.g_idx) + 1)
+        groups = {"g_idx": np.asarray(ev.g_idx, dtype=np.int32),
+                  "g_map": g_map, "g_off": f8(ev.g_off)}
+    return groups | {
         "flow_cap": f8(flow_cap),
         "dist_inc": f8(dist_inc), "coll_inc": f8(coll_inc),
         "M": f8(ev.M), "K": f8(ev.K), "N": f8(ev.N),
@@ -120,6 +133,37 @@ def consts_from_evaluator(ev) -> EvalConsts:
         "e_sram": f8(hw.e_sram_bit * 8.0), "e_mem": f8(hw.e_mem_bit * 8.0),
         "e_nop": f8(hw.e_nop_bit_hop * 8.0), "e_mac": f8(hw.e_mac_cycle),
     }
+
+
+def _by_op(c: EvalConsts, g, base):
+    """Per-op array: row j of ``g`` for grouped op j, ``base`` (broadcast
+    to the op axis) for every other op."""
+    full = jnp.concatenate([jnp.zeros_like(g[:1]), g])[c["g_map"]]
+    grouped = (c["g_map"] > 0).reshape((-1,) + (1,) * (g.ndim - 1))
+    return jnp.where(grouped, full, base)
+
+
+def _group_terms(c: EvalConsts, Px):
+    """The grouped-GEMM factors of ``Evaluator._group_terms`` for one
+    candidate: ``rows`` [n,X], ``fetch`` [n,E], ``held`` [n], ``tiles``
+    [n,X]. Tile counts are integer ceil-divisions, exact on any device."""
+    X = Px.shape[1]
+    Pg = Px[c["g_idx"]]                                         # [ng,X]
+    hi = cumsum_seq(Pg, axis=-1)
+    lo = hi - Pg
+    off = c["g_off"]
+    r = jnp.maximum(jnp.minimum(hi[..., None], off[:, None, 1:])
+                    - jnp.maximum(lo[..., None], off[:, None, :-1]), 0.0)
+    has = (r > 0).astype(Px.dtype)                              # [ng,X,G]
+    t = has.sum(axis=-1)
+    fetch = (jnp.einsum("ex,jxg->jeg", c["row_mask"], has) > 0).sum(axis=-1)
+    R = c["R"].astype(jnp.int32)
+    tiles = ((jnp.rint(r).astype(jnp.int32) + R - 1) // R).sum(axis=-1)
+    return {"rows": _by_op(c, t, 1.0),
+            "fetch": _by_op(c, fetch.astype(Px.dtype), 1.0),
+            "held": _by_op(c, t.sum(axis=-1), float(X)),
+            "tiles": _by_op(c, tiles.astype(Px.dtype),
+                            jnp.ceil(Px / c["R"]))}
 
 
 def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
@@ -165,10 +209,21 @@ def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
     # ----------------------------------------------- phase 1: data load
     A_e = jnp.einsum("ex,nx->ne", c["row_mask"], inA)
     W_e = jnp.einsum("ey,ny->ne", c["col_mask"], inW)
+    grouped = _group_terms(c, Px) if "g_idx" in c else None
+    if grouped is not None:
+        W_e = W_e * grouped["fetch"]
+
+    def inW_xy():
+        """Weight bytes per chiplet, [n,1|X,Y]; built at each use, as the
+        broadcast was before group tables existed, so that a task without
+        them lowers to the same program."""
+        w = inW[:, None, :]
+        return w if grouped is None else w * grouped["rows"][:, :, None]
+
     t_off_in = ((keepA[:, None] * A_e + W_e) / bw_ent).max(axis=-1)
 
     tA_xy = inA[:, :, None] * c["hA"][None]                    # bytes*hops
-    tW_xy = inW[:, None, :] * c["hW"][None]
+    tW_xy = inW_xy() * c["hW"][None]
     nop_in_xy = ((keepA[:, None, None] * tA_xy + tW_xy)
                  / c["bw_nop_xy"][None])
     t_nop_in = nop_in_xy.max(axis=(-1, -2))
@@ -184,7 +239,7 @@ def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
         d_routed = (c["dist_inc"].sum(axis=1) > 0).astype(inA.dtype)
         c_routed = (c["coll_inc"].sum(axis=1) > 0).astype(inA.dtype)
         demand = (keepA[:, None, None] * inA[:, :, None]
-                  + inW[:, None, :]).reshape(n, X * Y) * d_routed
+                  + inW_xy()).reshape(n, X * Y) * d_routed
 
         def dist_one(b):
             _, done, _, events, fills = waterfill_times(
@@ -209,7 +264,8 @@ def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
     if smooth:
         tiles = (Px / R)[:, :, None] * (Py / C)[:, None, :]
     else:
-        tiles = jnp.ceil(Px / R)[:, :, None] * jnp.ceil(Py / C)[:, None, :]
+        row_tiles = jnp.ceil(Px / R) if grouped is None else grouped["tiles"]
+        tiles = row_tiles[:, :, None] * jnp.ceil(Py / C)[:, None, :]
     cyc = fill * tiles
     cyc = cyc + c["epilogue"][:, None, None] * Px[:, :, None] \
         * Py[:, None, :] / C
@@ -267,7 +323,8 @@ def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
     latency = t_ops.sum()
 
     # ------------------------------------------------------- energy
-    sram_bytes = (Y * inA.sum(axis=-1) + X * inW.sum(axis=-1)
+    w_held = X if grouped is None else grouped["held"]      # rows holding W
+    sram_bytes = (Y * inA.sum(axis=-1) + w_held * inW.sum(axis=-1)
                   + chunk.sum(axis=(-1, -2)))
     E_sram = c["e_sram"] * sram_bytes.sum()
 

@@ -49,11 +49,11 @@ import jax.numpy as jnp
 from jax import lax, random
 
 from ..runtime.spans import span
-from .evaluator import EvalOptions, Evaluator
+from .evaluator import EvalOptions, Evaluator, group_rows
 from .evaluator_jax import _eval_single, _host_bytes
 from .ga import MOVE_ATTEMPTS
 from .hw import HWConfig
-from .workload import Partition, Task, partition_domain
+from .workload import Partition, Task, group_counts, partition_domain
 from .x64 import x64
 
 __all__ = ["run_ga_jax", "solve_islands"]
@@ -280,7 +280,7 @@ def solve_islands(
             win["lo_y"].append(lo[:, 1])
             win["hi_y"].append(hi[:, 1])
         win = {k: np.stack(v).astype(np.float64) for k, v in win.items()}
-        sp.set_metadata(bytes=_host_bytes(consts, win))
+        sp.set_metadata(bytes=_host_bytes(consts, win), **group_counts(tasks))
     with span("ga.init", islands=G, population=pop) as sp:
         # Shared host init (per-island RNG seeded by cfg.seed alone, so a
         # point's result never depends on its position in the grid). The
@@ -370,7 +370,7 @@ def solve_islands(
             if stop:
                 break
 
-    with span("ga.results"):
+    with span("ga.results") as sp:
         best_obj = np.asarray(carry[_BEST_OBJ])
         bPx, bPy, bco, brd = (np.asarray(carry[i]) for i in (5, 6, 7, 8))
         steps = np.asarray(carry[_STEPS])
@@ -391,7 +391,19 @@ def solve_islands(
                 history=best_all[g, :T].copy(),
                 evaluations=T * pop,
             ))
+        if any(len(ev.g_idx) for ev in evs):
+            sp.set_metadata(expert_fetch_excess=sum(
+                fetch_excess(ev, r.partition)
+                for ev, r in zip(evs, results)))
     return results
+
+
+def fetch_excess(ev: Evaluator, part: Partition) -> int:
+    """Group weight fetches a partition adds over one per group: Σ_x t_x
+    (groups each chiplet row needs) less the groups that have rows,
+    summed over the task's grouped ops."""
+    r = group_rows(part.Px[ev.g_idx].astype(np.float64), ev.g_off)
+    return int((r > 0).sum() - (np.diff(ev.g_off, axis=-1) > 0).sum())
 
 
 def run_ga_jax(task: Task, hw: HWConfig, objective: str,

@@ -213,6 +213,11 @@ def run_miqp(
 
         return miqp_jax.solve_lattice_batch(
             [task], [hw], options, objective, cfg)[0]
+    if task.group_shape[0]:
+        raise ValueError(
+            f"engine='milp' cannot take uneven grouped GEMMs "
+            f"({task.name}): its linearization prices weights by "
+            f"w_scale; use engine='lattice'")
     if hw.is_hetero:
         # The HiGHS formulation linearizes against the package-scalar
         # rates; per-chiplet rates need the lattice engine, which scores

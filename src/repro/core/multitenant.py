@@ -298,6 +298,12 @@ def solve_multitenant(
     tasks = tuple(tasks)
     if not tasks:
         raise ValueError("need at least one tenant task")
+    for t in tasks:
+        if t.group_shape[0]:
+            raise ValueError(
+                f"multi-tenant placement cannot take uneven grouped GEMMs "
+                f"({t.name}): its contention model prices weights by "
+                f"w_scale")
     if len(tasks) > hw.X:
         raise ValueError(f"{len(tasks)} tenants need {len(tasks)} row "
                          f"bands but the grid has X={hw.X} rows")

@@ -76,7 +76,7 @@ import numpy as np
 from ..runtime.spans import span
 from .evaluator import EvalOptions, Evaluator
 from .hw import HWConfig
-from .workload import Partition, Task, uniform_partition
+from .workload import Partition, Task, group_counts, uniform_partition
 
 __all__ = [
     "EvalPoint",
@@ -472,7 +472,8 @@ def _eval_sweep(points, backend: str, cache: bool, devices):
                 pt = points[i]
                 ev = Evaluator(pt.task, pt.hw, pt.options, backend="jax")
                 evs[i] = ev
-                sig = (len(pt.task), pt.hw.X, pt.hw.Y, ev.top.n_entrances,
+                sig = (len(pt.task), pt.task.group_shape, pt.hw.X, pt.hw.Y,
+                       ev.top.n_entrances,
                        pt.options.redistribution, pt.options.async_exec,
                        pt.options.energy_mode, pt.options.congestion)
                 groups.setdefault(sig, []).append(i)
@@ -488,7 +489,8 @@ def _eval_sweep(points, backend: str, cache: bool, devices):
                 Px, Py, co, rd = (np.stack([g[j] for g in genomes])[:, None]
                                   for j in range(4))
                 sp.set_metadata(bytes=sum(
-                    a.nbytes for a in (*stacked.values(), Px, Py, co, rd)))
+                    a.nbytes for a in (*stacked.values(), Px, Py, co, rd)),
+                    **group_counts([points[i].task for i in idxs]))
             out = evaluator_jax.grid_evaluate(
                 stacked, points[idxs[0]].options, Px, Py, co, rd,
                 devices=(points[idxs[0]].options.devices
@@ -771,7 +773,7 @@ def _solve_grid_ga(points, objective: str, cfg, backend: str, cache: bool,
         with span("sweep.group") as sp:
             for i in todo:
                 pt = points[i]
-                sig = (len(pt.task), pt.hw.X, pt.hw.Y,
+                sig = (len(pt.task), pt.task.group_shape, pt.hw.X, pt.hw.Y,
                        pt.hw.topology.n_entrances,
                        _strip_devices(pt.options))
                 groups.setdefault(sig, []).append(i)
@@ -1138,7 +1140,7 @@ def _solve_grid_miqp(points, objective, cfg, backend, cache,
         groups: dict[tuple, list[int]] = {}
         for i in todo:
             pt = points[i]
-            sig = (len(pt.task), pt.hw.X, pt.hw.Y,
+            sig = (len(pt.task), pt.task.group_shape, pt.hw.X, pt.hw.Y,
                    pt.hw.topology.n_entrances, _strip_devices(pt.options))
             groups.setdefault(sig, []).append(i)
         for sig, idxs in groups.items():

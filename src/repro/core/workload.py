@@ -10,6 +10,11 @@ SIMD-class operators (ReLU, softmax, layernorm — Sec. 4.2.2) are modeled as
 attributes of the preceding GEMM: ``epilogue_flops_per_elem`` adds vector
 cycles, and ``sync=True`` forces an output synchronization (softmax /
 layernorm over distributed outputs).
+
+An uneven grouped GEMM (routed experts) carries ``group_sizes``: its ``M``
+rows are the groups' rows in group order, and each group has weights of
+its own, so a chiplet row fetches and tiles only the groups its rows
+cover (DESIGN.md §5, "Grouped GEMMs").
 """
 from __future__ import annotations
 
@@ -17,7 +22,8 @@ import dataclasses
 
 import numpy as np
 
-__all__ = ["GemmOp", "Task", "uniform_partition", "partition_domain", "Partition"]
+__all__ = ["GemmOp", "Task", "uniform_partition", "partition_domain", "Partition",
+           "group_counts"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,12 +41,25 @@ class GemmOp:
     weight_bytes_scale: float = 1.0  # grouped GEMMs reuse one weight tile
     epilogue_flops_per_elem: int = 0  # SIMD epilogue (ReLU=1, softmax≈5, ...)
     n_groups: int = 1           # grouped GEMM (e.g. attention heads)
+    # Rows per group of an uneven grouped GEMM, in row order (each group
+    # its own weights, e.g. tokens per routed expert); () = not grouped.
+    group_sizes: tuple[int, ...] = ()
 
     def __post_init__(self):
         for d in (self.M, self.K, self.N):
             if d < 1:
                 raise ValueError(f"bad GEMM dims in {self.name}: "
                                  f"{self.M}x{self.K}x{self.N}")
+        sizes = tuple(int(g) for g in self.group_sizes)
+        object.__setattr__(self, "group_sizes", sizes)
+        if sizes:
+            if min(sizes) < 0 or sum(sizes) != self.M:
+                raise ValueError(f"{self.name}: group_sizes must be >= 0 "
+                                 f"and sum to M={self.M}")
+            if self.weight_bytes_scale != 1.0:
+                raise ValueError(f"{self.name}: a grouped op's weights "
+                                 f"follow its groups; weight_bytes_scale "
+                                 f"must be 1")
 
     @property
     def flops(self) -> int:
@@ -80,9 +99,28 @@ class Task:
     def total_flops(self) -> int:
         return sum(op.flops for op in self.ops)
 
+    @property
+    def group_shape(self) -> tuple[int, int]:
+        """(grouped ops, widest group count): the shape of the group
+        tables, (0, 0) for a task without grouped ops."""
+        sizes = [len(op.group_sizes) for op in self.ops if op.group_sizes]
+        return len(sizes), max(sizes, default=0)
+
     def arrays(self) -> dict[str, np.ndarray]:
-        """Stack op attributes into arrays for the vectorized evaluator."""
+        """Stack op attributes into arrays for the vectorized evaluator.
+
+        ``g_idx`` [ng] lists the grouped ops and ``g_off`` [ng, G+1] their
+        group row offsets (exclusive cumulative sums of ``group_sizes``),
+        padded to the widest op with empty groups at ``M``."""
         f = lambda a: np.array([getattr(op, a) for op in self.ops])
+        ng, width = self.group_shape
+        g_idx = np.array([i for i, op in enumerate(self.ops)
+                          if op.group_sizes], dtype=np.int32)
+        g_off = np.zeros((ng, width + 1), dtype=np.float64)
+        for j, i in enumerate(g_idx):
+            cs = np.cumsum(self.ops[i].group_sizes)
+            g_off[j, 1:len(cs) + 1] = cs
+            g_off[j, len(cs) + 1:] = self.ops[i].M
         return {
             "M": f("M"),
             "K": f("K"),
@@ -93,6 +131,8 @@ class Task:
             "chained": f("chained"),
             "w_scale": f("weight_bytes_scale"),
             "epilogue": f("epilogue_flops_per_elem"),
+            "g_idx": g_idx,
+            "g_off": g_off,
         }
 
     def describe(self) -> str:
@@ -108,6 +148,14 @@ class Task:
                 f"  {op.name:<24} M={op.M:<7} K={op.K:<7} N={op.N:<7} {flags}"
             )
         return "\n".join(rows)
+
+
+def group_counts(tasks) -> dict[str, int]:
+    """Span arguments of a call's group tables: its grouped ops and their
+    groups, summed over the call's tasks."""
+    sizes = [len(op.group_sizes) for t in tasks for op in t.ops
+             if op.group_sizes]
+    return {"grouped_ops": len(sizes), "groups": sum(sizes)}
 
 
 # --------------------------------------------------------------------------

@@ -50,7 +50,9 @@ __all__ = ["CacheStore", "SCHEMA_VERSION", "MAGIC"]
 #: way pickle cannot bridge; old stores then load as a cold start.
 #: v2: configs/options grew the §15 ``devices`` field — pre-v2 pickles
 #: would unpickle into dataclasses missing it and break fingerprinting.
-SCHEMA_VERSION = 2
+#: v3: ``GemmOp`` grew ``group_sizes`` (uneven grouped GEMMs), a field of
+#: every task fingerprint.
+SCHEMA_VERSION = 3
 MAGIC = "mcmcomm-sweep-cache"
 
 _LEN = struct.Struct("<II")    # payload_len, crc32

@@ -119,6 +119,21 @@ def test_schema_mismatch_reports_reason(tmp_path, monkeypatch):
     assert "schema" in store.last_load.reason
 
 
+def test_version_2_store_cold_starts(tmp_path, monkeypatch):
+    """Stores written before ``GemmOp.group_sizes`` existed (schema 2)
+    hold task fingerprints of another shape: they load as a cold start
+    and say why."""
+    assert cache_store.SCHEMA_VERSION == 3
+    path = tmp_path / "v2.bin"
+    monkeypatch.setattr(cache_store, "SCHEMA_VERSION", 2)
+    CacheStore(path).save(_populated_cache())
+    monkeypatch.undo()
+    store = CacheStore(path)
+    assert store.load() == {}
+    assert store.last_load.cold_start
+    assert store.last_load.reason.startswith("schema 2 != 3")
+
+
 def test_missing_and_foreign_files_cold_start(tmp_path):
     store = CacheStore(tmp_path / "absent.bin")
     assert store.load() == {}

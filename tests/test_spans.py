@@ -21,7 +21,7 @@ EVAL_SPANS = {
     "sweep.eval": {"call", "points"},
     "sweep.lookup": {"hits", "misses"},
     "sweep.group": {"groups"},
-    "sweep.consts": {"points", "bytes"},
+    "sweep.consts": {"points", "bytes", "grouped_ops", "groups"},
     "eval.to_device": {"bytes"},
     "eval.call": set(),
     "eval.fetch": set(),
@@ -33,7 +33,7 @@ SOLVE_SPANS = {
     "sweep.solve": {"call", "points"},
     "sweep.lookup": {"hits", "misses"},
     "sweep.group": {"groups"},
-    "ga.consts": {"islands", "bytes"},
+    "ga.consts": {"islands", "bytes", "grouped_ops", "groups"},
     "ga.init": {"islands", "population", "distinct"},
     "ga.to_device": {"bytes"},
     "ga.chunk": {"generations"},
@@ -128,6 +128,7 @@ def test_eval_sweep_spans(tmp_path):
     assert first["sweep.group"] == [{"groups": 1}]
     consts, = first["sweep.consts"]
     assert consts["points"] == 3
+    assert consts["grouped_ops"] == consts["groups"] == 0
     # what the sweep driver stacked is what goes to the device
     assert first["eval.to_device"] == [{"bytes": consts["bytes"]}]
     assert first["sweep.records"] == [{"records": 3}, {"records": 3}]
@@ -191,6 +192,7 @@ def test_solve_grid_spans(tmp_path):
                                 "distinct": 1}]
     consts, = call["ga.consts"]
     assert consts["islands"] == 2 and consts["bytes"] > 0
+    assert consts["grouped_ops"] == consts["groups"] == 0
     # constants, windows and the initial genomes go to the device
     assert call["ga.to_device"][0]["bytes"] > consts["bytes"]
     assert call["ga.chunk"] == [{"generations": 2}] * len(call["ga.chunk"])

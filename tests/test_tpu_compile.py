@@ -48,6 +48,14 @@ TASK = Task("chain", [
     GemmOp("g1", M=512, K=512, N=256, chained=True, sync=True),
     GemmOp("g2", M=512, K=256, N=512, chained=True),
 ])
+#: Routed experts: two uneven grouped GEMMs (DESIGN.md §5).
+_EXPERTS = (96, 0, 40, 200, 64, 8, 120, 56, 16, 88, 144, 24, 72, 0, 32, 64)
+GROUPED = Task("experts", [
+    GemmOp("router", M=512, K=256, N=16, sync=True),
+    GemmOp("up", M=1024, K=256, N=512, group_sizes=_EXPERTS),
+    GemmOp("down", M=1024, K=512, N=256, chained=True,
+           group_sizes=_EXPERTS),
+])
 HW = make_hw("A", 4, "hbm")
 OPTS = EvalOptions(redistribution=True, async_exec=True)
 
@@ -83,23 +91,23 @@ def _capture(monkeypatch, module, factory_name):
     monkeypatch.setattr(module, factory_name, factory)
 
 
-def _run_evaluator(congestion):
+def _run_evaluator(congestion, task=TASK):
     from repro.core import Evaluator
     from repro.core.workload import uniform_partition
 
     opts = dataclasses.replace(OPTS, congestion=congestion)
-    part = uniform_partition(TASK, HW.X, HW.Y)
-    Evaluator(TASK, HW, opts, backend="jax").evaluate_batch(
+    part = uniform_partition(task, HW.X, HW.Y)
+    Evaluator(task, HW, opts, backend="jax").evaluate_batch(
         np.repeat(part.Px[None], 8, 0).astype(float),
         np.repeat(part.Py[None], 8, 0).astype(float),
-        np.repeat(part.collectors[None], 8, 0), np.ones((8, len(TASK))))
+        np.repeat(part.collectors[None], 8, 0), np.ones((8, len(task))))
 
 
-def _run_ga():
+def _run_ga(task=TASK):
     from repro.core import GAConfig
     from repro.core.ga_jax import run_ga_jax
 
-    run_ga_jax(TASK, HW, "edp", OPTS,
+    run_ga_jax(task, HW, "edp", OPTS,
                GAConfig(population=8, generations=2, patience=2))
 
 
@@ -139,6 +147,9 @@ ENGINES = {
     "evaluator_flow": ("evaluator_jax", "population_fn",
                        lambda: _run_evaluator("flow")),
     "ga_chunk": ("ga_jax", "_chunk_fn", _run_ga),
+    "evaluator_grouped_flow": ("evaluator_jax", "population_fn",
+                               lambda: _run_evaluator("flow", GROUPED)),
+    "ga_chunk_grouped": ("ga_jax", "_chunk_fn", lambda: _run_ga(GROUPED)),
     "miqp_scoring": ("evaluator_jax", "grid_fn", _run_miqp),
     "sgs": ("pipelining_jax", "_sched_fn", _run_sgs),
     "netsim": ("netsim_jax", "_batch_fn", _run_netsim),
