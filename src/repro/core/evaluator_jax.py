@@ -53,7 +53,7 @@ __all__ = [
 EvalConsts = Dict[str, Any]
 
 #: Array-valued keys ([n]: per-op, [X,Y]: per-chiplet, [E...]: per-entrance,
-#: [L]/[XY,L]: link-level flow network, DESIGN.md §11) followed by the 0-d
+#: [2,W]/[XY,W]: each flow site's links, DESIGN.md §11) followed by the 0-d
 #: scalar keys. Order is the canonical stacking order used by the sweep
 #: engine.
 CONST_KEYS = (
@@ -100,7 +100,8 @@ def consts_from_evaluator(ev) -> EvalConsts:
     hw = ev.hw
     f8 = lambda a: np.asarray(a, dtype=np.float64)
     if ev.opts.congestion == "flow":
-        flow_cap, dist_inc, coll_inc = ev.top.flow_net()
+        # each site over the links its flows use (Topology.flow_sites)
+        flow_cap, dist_inc, coll_inc = ev.top.flow_sites()
     else:
         # Regime mode never reads the flow network; ship 1-element
         # placeholders instead of the [X·Y, L] incidence matrices —
@@ -243,12 +244,12 @@ def _eval_single(c: EvalConsts, Px, Py, collectors, redist, *,
 
         def dist_one(b):
             _, done, _, events, fills = waterfill_times(
-                c["flow_cap"], c["dist_inc"], b)
+                c["flow_cap"][0], c["dist_inc"], b)
             return done, events, fills
 
         def coll_one(b):
             t, _, _, events, fills = waterfill_times(
-                c["flow_cap"], c["coll_inc"], b)
+                c["flow_cap"][1], c["coll_inc"], b)
             return t, events, fills
 
         dist_done, dist_events, dist_fills = jax.vmap(dist_one)(demand)
@@ -461,8 +462,10 @@ def grid_evaluate(consts_stack: EvalConsts, opts: EvalOptions,
     In flow mode each netsim call site leaves one ``eval.lanes`` marker
     span: its ``lanes`` (grid x population x ops), the event-loop
     iterations summed over them and their maximum (the lockstep count of
-    the vmapped loop), and the same two for the waterfilling iterations
-    (whose lockstep count is at least ``fills_max``)."""
+    the vmapped loop), the same two for the waterfilling iterations
+    (whose lockstep count is at least ``fills_max``), the ``links`` its
+    loops ran over and ``links_used``, the most of them any grid point's
+    flows use."""
     G = int(np.shape(Px)[0])
     fn = grid_fn(*_static_key(opts))
     from . import sweep_shard
@@ -478,10 +481,13 @@ def grid_evaluate(consts_stack: EvalConsts, opts: EvalOptions,
         if f"{site}_events" in lanes:
             events = lanes[f"{site}_events"]
             fills = lanes[f"{site}_fills"]
+            inc = np.asarray(consts_stack[f"{site}_inc"])       # [G,F,W]
             with span("eval.lanes", site=site, lanes=int(events.size),
                       events_sum=int(events.sum(dtype=np.int64)),
                       events_max=int(events.max(initial=0)),
                       fills_sum=int(fills.sum(dtype=np.int64)),
-                      fills_max=int(fills.max(initial=0))):
+                      fills_max=int(fills.max(initial=0)),
+                      links=int(inc.shape[-1]),
+                      links_used=int(inc.any(axis=-2).sum(axis=-1).max())):
                 pass
     return out

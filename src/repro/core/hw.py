@@ -320,6 +320,7 @@ class Topology:
 
         self._build_hop_matrices()
         self._flow_net = None
+        self._flow_sites = None
 
     # ----------------------------------------------------------------- hops
     def _build_hop_matrices(self):
@@ -383,6 +384,37 @@ class Topology:
                 coll,
             )
         return self._flow_net
+
+    def flow_sites(self):
+        """:meth:`flow_net` as the jitted evaluator runs it (DESIGN.md
+        §11): ``(cap [2, W], dist_inc [X·Y, W], coll_inc [X·Y, W])``,
+        row 0 of ``cap`` the distribution site's, row 1 the collection
+        site's.
+
+        Each site keeps, in their original order, only the links its
+        flows use, and is padded to ``W = X·Y`` with all-zero incidence
+        columns of capacity 1.0. A link no flow uses never has a user, so
+        waterfilling never picks it and the rates, completion times and
+        lane counts are bitwise those of the full network. If a site
+        uses more than X·Y links, both keep the whole link axis
+        (``W = n_links``).
+        """
+        if self._flow_sites is None:
+            cap, dist, coll = self.flow_net()
+            width = self.hw.X * self.hw.Y
+            used = [np.flatnonzero(inc.any(axis=0)) for inc in (dist, coll)]
+            if max(len(u) for u in used) > width:
+                self._flow_sites = (np.stack([cap, cap]), dist, coll)
+            else:
+                caps = np.ones((2, width))
+                incs = []
+                for site, (inc, u) in enumerate(zip((dist, coll), used)):
+                    caps[site, :len(u)] = cap[u]
+                    out = np.zeros((inc.shape[0], width))
+                    out[:, :len(u)] = inc[:, u]
+                    incs.append(out)
+                self._flow_sites = (caps, *incs)
+        return self._flow_sites
 
     # ------------------------------------------------------------- helpers
     def describe(self) -> str:

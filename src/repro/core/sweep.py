@@ -197,6 +197,16 @@ def _strip_devices(obj):
     return obj
 
 
+def _flow_width(pt) -> int:
+    """Link width of the point's flow sites in the jitted evaluator
+    (``Topology.flow_sites``), 0 outside flow mode: part of every
+    shape-group signature whose group stacks constants, so no group
+    stacks a compacted network with a full one."""
+    if pt.options.congestion != "flow":
+        return 0
+    return pt.hw.topology.flow_sites()[0].shape[1]
+
+
 def _point_fingerprint(pt: EvalPoint, backend: str) -> tuple:
     part = pt.resolved_partition()
     rd = (None if pt.redist_mask is None
@@ -473,7 +483,7 @@ def _eval_sweep(points, backend: str, cache: bool, devices):
                 ev = Evaluator(pt.task, pt.hw, pt.options, backend="jax")
                 evs[i] = ev
                 sig = (len(pt.task), pt.task.group_shape, pt.hw.X, pt.hw.Y,
-                       ev.top.n_entrances,
+                       ev.top.n_entrances, _flow_width(pt),
                        pt.options.redistribution, pt.options.async_exec,
                        pt.options.energy_mode, pt.options.congestion)
                 groups.setdefault(sig, []).append(i)
@@ -774,7 +784,7 @@ def _solve_grid_ga(points, objective: str, cfg, backend: str, cache: bool,
             for i in todo:
                 pt = points[i]
                 sig = (len(pt.task), pt.task.group_shape, pt.hw.X, pt.hw.Y,
-                       pt.hw.topology.n_entrances,
+                       pt.hw.topology.n_entrances, _flow_width(pt),
                        _strip_devices(pt.options))
                 groups.setdefault(sig, []).append(i)
             sp.set_metadata(groups=len(groups))
@@ -867,7 +877,8 @@ def cosearch_sweep(
         for i in todo:
             pt = points[i]
             sig = (len(pt.task), pt.hw.X, pt.hw.Y,
-                   pt.hw.topology.n_entrances, _strip_devices(pt.options))
+                   pt.hw.topology.n_entrances, _flow_width(pt),
+                   _strip_devices(pt.options))
             groups.setdefault(sig, []).append(i)
         for sig, idxs in groups.items():
             outs = cosearch_islands(
@@ -1141,7 +1152,8 @@ def _solve_grid_miqp(points, objective, cfg, backend, cache,
         for i in todo:
             pt = points[i]
             sig = (len(pt.task), pt.task.group_shape, pt.hw.X, pt.hw.Y,
-                   pt.hw.topology.n_entrances, _strip_devices(pt.options))
+                   pt.hw.topology.n_entrances, _flow_width(pt),
+                   _strip_devices(pt.options))
             groups.setdefault(sig, []).append(i)
         for sig, idxs in groups.items():
             outs = miqp_jax.solve_lattice_batch(

@@ -26,7 +26,7 @@ EVAL_SPANS = {
     "eval.call": set(),
     "eval.fetch": set(),
     "eval.lanes": {"site", "lanes", "events_sum", "events_max",
-                   "fills_sum", "fills_max"},
+                   "fills_sum", "fills_max", "links", "links_used"},
     "sweep.records": {"records"},
 }
 SOLVE_SPANS = {
@@ -148,15 +148,50 @@ def test_eval_sweep_spans(tmp_path):
     assert sorted(lanes) == sorted(evaluator_jax.LANE_KEYS)
     marks = {m["site"]: m for m in first["eval.lanes"]}
     assert sorted(marks) == ["coll", "dist"]
+    _, dist, coll = hw.topology.flow_net()
     for site, m in marks.items():
         ev_, fi = lanes[f"{site}_events"], lanes[f"{site}_fills"]
         assert ev_.shape == (3, 1, len(task))
+        full = dist if site == "dist" else coll
         assert m == {"site": site, "lanes": 3 * len(task),
                      "events_sum": int(ev_.sum()),
                      "events_max": int(ev_.max()),
-                     "fills_sum": int(fi.sum()), "fills_max": int(fi.max())}
+                     "fills_sum": int(fi.sum()), "fills_max": int(fi.max()),
+                     "links": 16, "links_used": int(full.any(axis=0).sum())}
         assert 0 < m["events_sum"] <= m["lanes"] * m["events_max"]
         assert m["fills_sum"] >= m["events_sum"]
+
+
+def test_eval_lanes_links(tmp_path):
+    """``eval.lanes`` names the width of each flow site's link axis and
+    the most links any point's flows use there: X·Y and the used links
+    of the package's routes on a 4x4 grid, one group per package type;
+    the whole link axis for a network whose flows use more than X·Y."""
+    task = WORKLOADS["alexnet"](batch=1)
+    opts = EvalOptions(congestion="flow")
+    hws = [make_hw(t, 4, "hbm") for t in "ABD"]
+    hand = dataclasses.replace(hws[0], bw_nop=30e9)
+    cap, dist, coll = hand.topology.flow_net()
+    hand.topology._flow_net = (cap, dist + coll, coll)
+    hws.append(hand)
+    points = [sweep.EvalPoint(task, h, opts) for h in hws]
+    try:
+        _, spans = _traced(tmp_path, lambda: sweep.eval_sweep(points))
+    finally:
+        sweep.clear_cache()
+    call, = _calls(spans, "sweep.eval", EVAL_SPANS)
+    assert call["sweep.group"] == [{"groups": 4}]
+    marks = call["eval.lanes"]
+    assert [m["site"] for m in marks] == ["dist", "coll"] * 4
+    for j, h in enumerate(hws):
+        _, d, c = h.topology.flow_net()
+        used = [int(inc.any(axis=0).sum()) for inc in (d, c)]
+        # both sites keep the whole axis if either uses more than X·Y
+        width = 16 if max(used) <= 16 else cap.shape[0]
+        assert [m["links_used"] for m in marks[2 * j:2 * j + 2]] == used
+        assert [m["links"] for m in marks[2 * j:2 * j + 2]] == [width] * 2
+    assert marks[0]["links_used"] == 15    # type A: a tree from the corner
+    assert marks[6]["links"] == cap.shape[0] > 16
 
 
 def test_solve_grid_spans(tmp_path):
