@@ -43,6 +43,12 @@ This module turns those hand-rolled Python loops into:
     records are not shared across them — results must not depend on
     evaluation order).
 
+Every family runs through one loop, :func:`_sweep`: checkpointing, the
+cache lookups, grouping by a shape key, one grouped device call per
+group (or a host call per point) and the cache store. A family supplies
+a :class:`_Family` record: its fingerprint, record copy, per-point host
+path, shape key and grouped call.
+
 Two orthogonal execution knobs ride on every sweep (DESIGN.md §15):
 
   * ``devices`` — ``"single" | "sharded" | "auto"`` shards each batched
@@ -65,6 +71,7 @@ Typical use (LS baselines for one figure)::
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import sys
@@ -353,10 +360,101 @@ def _checkpointed(points, ckpt: SweepCheckpointer, straggler, run_chunk):
     return out
 
 
+# ------------------------------------------------------- the sweep loop
+@dataclasses.dataclass(frozen=True)
+class _Family:
+    """One sweep kind as :func:`_sweep` drives it (DESIGN.md §9).
+
+    ``fingerprint(pt)`` is a point's cache key and ``copy`` moves its
+    record across the cache boundary; ``solo(pt)`` computes one point on
+    the host, ``batch(points)`` one shape group (all with the same
+    ``key(pt)``) in one grouped device call, returning records in order.
+    ``span`` names the call span (``sweep.eval`` / ``sweep.solve``);
+    ``None`` opens none."""
+
+    fingerprint: Callable[[Any], tuple]
+    copy: Callable[[Any], Any]
+    solo: Callable[[Any], Any] | None
+    key: Callable[[Any], tuple] | None
+    batch: Callable[[list], list] | None
+    span: str | None = None
+
+
+def _shape_key(pt: EvalPoint) -> tuple:
+    """The shape-group signature of the families that stack evaluator
+    constants (eval, GA, MIQP, co-search): the shapes of the stacked
+    arrays, and the static :class:`EvalOptions` of the compiled call
+    with the result-neutral ``devices`` knob stripped."""
+    return (len(pt.task), pt.task.group_shape, pt.hw.X, pt.hw.Y,
+            pt.hw.topology.n_entrances, _flow_width(pt),
+            _strip_devices(pt.options))
+
+
+def _call_devices(devices: str | None, default: str) -> str:
+    """The §15 device mode of a sweep's grouped calls: the sweep-level
+    ``devices`` wins; ``None`` defers to the family's ``default`` (the
+    group's ``options.devices`` for evaluation, ``cfg.devices`` for the
+    solvers and pipelining, ``"auto"`` for the netsim)."""
+    return default if devices is None else devices
+
+
+def _check_backend(backend: str, accepted=("numpy", "jax", "auto")):
+    """Refuse a backend that is neither engine once ``"auto"`` is
+    resolved; ``accepted`` is what the caller's argument may be."""
+    if backend not in ("numpy", "jax"):
+        raise ValueError(f"unknown backend {backend!r}; one of {accepted}")
+
+
+def _group_args(points) -> tuple:
+    """A solver group's engine arguments: its tasks, its packages and
+    the options the group shares."""
+    return ([pt.task for pt in points], [pt.hw for pt in points],
+            points[0].options)
+
+
+def _sweep(points, fam: _Family, batched: bool, cache: bool, checkpoint,
+           checkpoint_every: int, straggler) -> list:
+    """The loop every sweep family shares: checkpointed chunks, the call
+    span, cache lookups, then per shape group one ``fam.batch`` call
+    (``batched``) or ``fam.solo`` per point, and the cache inserts.
+    Returns records aligned with ``points``."""
+    ckpt = _resolve_checkpoint(checkpoint, checkpoint_every)
+    if ckpt is not None:
+        if not cache:
+            raise ValueError("checkpointing requires cache=True — "
+                             "records persist through the result cache")
+        return _checkpointed(
+            points, ckpt, straggler,
+            lambda c: _sweep(c, fam, batched, True, None, 1, None))
+    with (span(fam.span, call=next(_CALLS), points=len(points))
+          if fam.span else contextlib.nullcontext()):
+        records, todo, fps = _lookup(points, cache, fam.fingerprint,
+                                     fam.copy)
+        if todo and not batched:
+            for i in todo:
+                records[i] = fam.solo(points[i])
+        elif todo:
+            groups: dict[tuple, list[int]] = {}
+            with span("sweep.group") as sp:
+                for i in todo:
+                    groups.setdefault(fam.key(points[i]), []).append(i)
+                sp.set_metadata(groups=len(groups))
+            for idxs in groups.values():
+                outs = fam.batch([points[i] for i in idxs])
+                for i, out in zip(idxs, outs):
+                    records[i] = out
+        if cache:
+            # records enter the cache by value, like every hit leaves it
+            with span("sweep.records", records=len(todo)):
+                for i in todo:
+                    _CACHE[fps[i]] = fam.copy(records[i])
+    return records
+
+
 def _lookup(points, cache: bool, fingerprint, copy):
     """The cache lookups of one sweep call: ``(records, todo, fps)``,
     records filled for the hits, ``todo`` the indices left to compute;
-    ``fingerprint(i)`` is point ``i``'s cache key. Spanned as
+    ``fingerprint(pt)`` is a point's cache key. Spanned as
     ``sweep.lookup`` with the call's hits and misses."""
     records: list = [None] * len(points)
     todo: list[int] = []
@@ -364,7 +462,7 @@ def _lookup(points, cache: bool, fingerprint, copy):
     with span("sweep.lookup") as sp:
         for i in range(len(points)):
             if cache:
-                fp = fingerprint(i)
+                fp = fingerprint(points[i])
                 fps[i] = fp
                 hit = _CACHE.get(fp)
                 if hit is not None:
@@ -376,13 +474,6 @@ def _lookup(points, cache: bool, fingerprint, copy):
         misses = len(todo) if cache else 0
         sp.set_metadata(hits=len(points) - len(todo), misses=misses)
     return records, todo, fps
-
-
-def _store(records, todo, fps, copy) -> None:
-    """Insert a call's computed records into the cache, by value."""
-    with span("sweep.records", records=len(todo)):
-        for i in todo:
-            _CACHE[fps[i]] = copy(records[i])
 
 
 def _record(point: EvalPoint, out: dict[str, np.ndarray], i: int | tuple
@@ -441,77 +532,44 @@ def eval_sweep(
     path or :class:`SweepCheckpointer`) persists records every
     ``checkpoint_every`` points for kill/resume; requires ``cache=True``.
     """
-    if backend not in ("numpy", "jax"):
-        raise ValueError(f"unknown backend {backend!r}; "
-                         f"one of ('numpy', 'jax')")
-    ckpt = _resolve_checkpoint(checkpoint, checkpoint_every)
-    if ckpt is not None:
-        if not cache:
-            raise ValueError("checkpointing requires cache=True — "
-                             "records persist through the result cache")
-        return _checkpointed(
-            points, ckpt, straggler,
-            lambda c: eval_sweep(c, backend=backend, cache=True,
-                                 devices=devices))
-    with span("sweep.eval", call=next(_CALLS), points=len(points)):
-        return _eval_sweep(points, backend, cache, devices)
+    _check_backend(backend, ("numpy", "jax"))
+    fam = _Family(lambda pt: _point_fingerprint(pt, backend), _copy_record,
+                  _eval_solo, _shape_key,
+                  lambda group: _eval_batch(group, devices), span="sweep.eval")
+    return _sweep(points, fam, backend == "jax", cache, checkpoint,
+                  checkpoint_every, straggler)
 
 
-def _eval_sweep(points, backend: str, cache: bool, devices):
-    """:func:`eval_sweep` without its checkpointing."""
-    records, todo, fps = _lookup(
-        points, cache, lambda i: _point_fingerprint(points[i], backend),
-        _copy_record)
+def _eval_solo(pt: EvalPoint) -> dict[str, Any]:
+    """One point through the numpy reference evaluator."""
+    ev = Evaluator(pt.task, pt.hw, pt.options, backend="numpy")
+    Px, Py, co, rd = _genome(pt, ev)
+    out = ev.evaluate_batch(Px[None], Py[None], co[None], rd[None])
+    return _record(pt, out, 0)
 
-    if todo and backend == "numpy":
-        for i in todo:
-            pt = points[i]
-            ev = Evaluator(pt.task, pt.hw, pt.options, backend="numpy")
-            Px, Py, co, rd = _genome(pt, ev)
-            out = ev.evaluate_batch(Px[None], Py[None], co[None], rd[None])
-            records[i] = _record(pt, out, 0)
-    elif todo:
-        from . import evaluator_jax
 
-        # Group by (shape signature, static options): one compiled+batched
-        # call per group.
-        groups: dict[tuple, list[int]] = {}
-        evs: dict[int, Evaluator] = {}
-        with span("sweep.group") as sp:
-            for i in todo:
-                pt = points[i]
-                ev = Evaluator(pt.task, pt.hw, pt.options, backend="jax")
-                evs[i] = ev
-                sig = (len(pt.task), pt.task.group_shape, pt.hw.X, pt.hw.Y,
-                       ev.top.n_entrances, _flow_width(pt),
-                       pt.options.redistribution, pt.options.async_exec,
-                       pt.options.energy_mode, pt.options.congestion)
-                groups.setdefault(sig, []).append(i)
-            sp.set_metadata(groups=len(groups))
+def _eval_batch(points, devices) -> list[dict[str, Any]]:
+    """One shape group: constants and genomes stacked on a leading grid
+    axis, evaluated by one compiled call."""
+    from . import evaluator_jax
 
-        for sig, idxs in groups.items():
-            with span("sweep.consts", points=len(idxs)) as sp:
-                consts = [evs[i].consts() for i in idxs]
-                stacked = {k: np.stack([c[k] for c in consts])
-                           for k in consts[0]}
-                genomes = [_genome(points[i], evs[i]) for i in idxs]
-                # [G,1,n,X], [G,1,n,Y], [G,1,n], [G,1,n]
-                Px, Py, co, rd = (np.stack([g[j] for g in genomes])[:, None]
-                                  for j in range(4))
-                sp.set_metadata(bytes=sum(
-                    a.nbytes for a in (*stacked.values(), Px, Py, co, rd)),
-                    **group_counts([points[i].task for i in idxs]))
-            out = evaluator_jax.grid_evaluate(
-                stacked, points[idxs[0]].options, Px, Py, co, rd,
-                devices=(points[idxs[0]].options.devices
-                         if devices is None else devices))
-            with span("sweep.records", records=len(idxs)):
-                for g, i in enumerate(idxs):
-                    records[i] = _record(points[i], out, (g, 0))
-
-    if cache:
-        _store(records, todo, fps, _copy_record)
-    return records  # type: ignore[return-value]
+    with span("sweep.consts", points=len(points)) as sp:
+        evs = [Evaluator(pt.task, pt.hw, pt.options, backend="jax")
+               for pt in points]
+        consts = [ev.consts() for ev in evs]
+        stacked = {k: np.stack([c[k] for c in consts]) for k in consts[0]}
+        genomes = [_genome(pt, ev) for pt, ev in zip(points, evs)]
+        # [G,1,n,X], [G,1,n,Y], [G,1,n], [G,1,n]
+        Px, Py, co, rd = (np.stack([g[j] for g in genomes])[:, None]
+                          for j in range(4))
+        sp.set_metadata(bytes=sum(
+            a.nbytes for a in (*stacked.values(), Px, Py, co, rd)),
+            **group_counts([pt.task for pt in points]))
+    out = evaluator_jax.grid_evaluate(
+        stacked, points[0].options, Px, Py, co, rd,
+        devices=_call_devices(devices, points[0].options.devices))
+    with span("sweep.records", records=len(points)):
+        return [_record(pt, out, (g, 0)) for g, pt in enumerate(points)]
 
 
 # ------------------------------------------------------ batched netsim
@@ -561,52 +619,33 @@ def netsim_sweep(
     persists records for kill/resume."""
     from . import netsim
 
-    if backend not in ("numpy", "jax"):
-        raise ValueError(f"unknown backend {backend!r}; "
-                         f"one of ('numpy', 'jax')")
-    ckpt = _resolve_checkpoint(checkpoint, checkpoint_every)
-    if ckpt is not None:
-        if not cache:
-            raise ValueError("checkpointing requires cache=True — "
-                             "records persist through the result cache")
-        return _checkpointed(
-            nets, ckpt, straggler,
-            lambda c: netsim_sweep(c, message_bytes, backend=backend,
-                                   cache=True, devices=devices))
-    records, todo, fps = _lookup(
-        nets, cache,
-        lambda i: _netsim_fingerprint(nets[i], message_bytes, backend),
-        _copy_record)
+    _check_backend(backend, ("numpy", "jax"))
 
-    if todo and backend == "numpy":
-        for i in todo:
-            net = nets[i]
-            out = netsim.simulate_flows(
-                net.pull_incidence(), net.link_caps(),
-                np.full(net.X * net.Y, float(message_bytes)))
-            records[i] = {"latency": float(out["latency"]),
-                          "done": out["done"], "link_bytes": out["link_bytes"]}
-    elif todo:
+    def solo(net):
+        out = netsim.simulate_flows(
+            net.pull_incidence(), net.link_caps(),
+            np.full(net.X * net.Y, float(message_bytes)))
+        return {"latency": float(out["latency"]),
+                "done": out["done"], "link_bytes": out["link_bytes"]}
+
+    def batch(group):
         from . import netsim_jax
 
-        groups: dict[tuple, list[int]] = {}
-        for i in todo:
-            groups.setdefault((nets[i].X, nets[i].Y), []).append(i)
-        for (X, Y), idxs in groups.items():
-            caps = np.stack([nets[i].link_caps() for i in idxs])
-            incs = np.stack([nets[i].pull_incidence() for i in idxs])
-            msgs = np.full((len(idxs), X * Y), float(message_bytes))
-            out = netsim_jax.simulate_pull_batch(
-                caps, incs, msgs,
-                devices="auto" if devices is None else devices)
-            for g, i in enumerate(idxs):
-                records[i] = {"latency": float(out["latency"][g]),
-                              "done": out["done"][g],
-                              "link_bytes": out["link_bytes"][g]}
+        out = netsim_jax.simulate_pull_batch(
+            np.stack([net.link_caps() for net in group]),
+            np.stack([net.pull_incidence() for net in group]),
+            np.full((len(group), group[0].X * group[0].Y),
+                    float(message_bytes)),
+            devices=_call_devices(devices, "auto"))
+        return [{"latency": float(out["latency"][g]), "done": out["done"][g],
+                 "link_bytes": out["link_bytes"][g]}
+                for g in range(len(group))]
 
-    if cache:
-        _store(records, todo, fps, _copy_record)
-    return records  # type: ignore[return-value]
+    fam = _Family(
+        lambda net: _netsim_fingerprint(net, message_bytes, backend),
+        _copy_record, solo, lambda net: (net.X, net.Y), batch)
+    return _sweep(nets, fam, backend == "jax", cache, checkpoint,
+                  checkpoint_every, straggler)
 
 
 # ----------------------------------------------------------- batched solves
@@ -632,8 +671,6 @@ def _solver_fingerprint(pt: EvalPoint, method: str, backend: str,
 
 
 def _copy_solver_record(rec):
-    import dataclasses as _dc
-
     from .cosearch import CoSearchResult
     from .ga import GAResult
     from .miqp import MIQPResult
@@ -641,7 +678,7 @@ def _copy_solver_record(rec):
     from .pipelining import PipelineResult
 
     if isinstance(rec, PipelineResult):
-        return _dc.replace(rec)      # all fields immutable scalars
+        return dataclasses.replace(rec)  # all fields immutable scalars
     if isinstance(rec, MultiTenantResult):
         return rec.copy()
     if isinstance(rec, CoSearchResult):
@@ -692,13 +729,13 @@ def solve_grid(
     ``points`` — ``GAResult`` for ``method="ga"`` (DESIGN.md §10),
     ``MIQPResult`` for ``method="miqp"`` (DESIGN.md §12).
 
-    JAX backend: uncached points are grouped by shape signature — (n_ops,
-    X, Y, n_entrances); the :class:`EvalOptions` statics live in the
-    compiled function's cache key — and each group batches through ONE
-    compiled program per call: GA searches evolve as *islands* of one
-    ``jit(vmap(scan))`` call (:func:`repro.core.ga_jax.solve_islands`);
-    MIQP lattice searches share the grid axis of the chunked scoring
-    calls (:func:`repro.core.miqp_jax.solve_lattice_batch`). Numpy
+    JAX backend: uncached points are grouped by shape signature
+    (:func:`_shape_key`, static options included) and each group
+    batches through ONE compiled program per call: GA searches evolve
+    as *islands* of one ``jit(vmap(scan))`` call
+    (:func:`repro.core.ga_jax.solve_islands`); MIQP lattice searches
+    share the grid axis of the chunked scoring calls
+    (:func:`repro.core.miqp_jax.solve_lattice_batch`). Numpy
     backend: per-point host engines — the fallback used by ``run.py
     --backend numpy``. A point's result (and its cache record) is
     identical whether it is solved alone or batched with others: GA
@@ -721,85 +758,41 @@ def solve_grid(
     :class:`SweepCheckpointer`) persists solver records every
     ``checkpoint_every`` points for kill/resume (``cache=True`` only);
     ``straggler`` flags outlier chunk wall-times to stderr."""
-    ckpt = _resolve_checkpoint(checkpoint, checkpoint_every)
-    if ckpt is not None:
-        if not cache:
-            raise ValueError("checkpointing requires cache=True — "
-                             "records persist through the result cache")
-        return _checkpointed(
-            points, ckpt, straggler,
-            lambda c: solve_grid(c, objective, cfg, backend=backend,
-                                 cache=True, method=method,
-                                 devices=devices))
+    ckpt_args = (checkpoint, checkpoint_every, straggler)
     if method == "miqp":
         return _solve_grid_miqp(points, objective, cfg, backend, cache,
-                                devices)
-    if method == "cosearch":
-        return cosearch_sweep(points, objective=objective, cfg=cfg,
-                              backend=backend, cache=cache,
-                              devices=devices)
-    if method == "multitenant":
-        return multitenant_sweep(points, objective=objective, cfg=cfg,
-                                 backend=backend, cache=cache,
-                                 devices=devices)
+                                devices, *ckpt_args)
+    if method in ("cosearch", "multitenant"):
+        family = cosearch_sweep if method == "cosearch" else \
+            multitenant_sweep
+        return family(points, objective, cfg, backend, cache, devices,
+                      *ckpt_args)
     if method != "ga":
         raise ValueError(f"unknown method {method!r}; "
                          f"one of ('ga', 'miqp', 'cosearch', "
                          f"'multitenant')")
     from .evaluator import resolve_auto_backend
-    from .ga import GAConfig
+    from .ga import GAConfig, run_ga
 
     if cfg is None:
         cfg = GAConfig()
     backend = resolve_auto_backend(backend, cfg.population)
-    if backend not in ("numpy", "jax"):
-        raise ValueError(f"unknown backend {backend!r}; "
-                         f"one of ('numpy', 'jax', 'auto')")
-    with span("sweep.solve", call=next(_CALLS), points=len(points)):
-        return _solve_grid_ga(points, objective, cfg, backend, cache,
-                              devices)
+    _check_backend(backend)
 
-
-def _solve_grid_ga(points, objective: str, cfg, backend: str, cache: bool,
-                   devices) -> list:
-    """:func:`solve_grid` for ``method="ga"``, without its checkpointing."""
-    from .ga import run_ga
-
-    records, todo, fps = _lookup(
-        points, cache,
-        lambda i: _solver_fingerprint(points[i], "ga", backend, objective,
-                                      cfg),
-        _copy_solver_record)
-
-    if todo and backend == "numpy":
-        for i in todo:
-            pt = points[i]
-            records[i] = run_ga(pt.task, pt.hw, objective, pt.options,
-                                cfg, backend="numpy", engine="vectorized")
-    elif todo:
+    def batch(group):
         from . import ga_jax
 
-        groups: dict[tuple, list[int]] = {}
-        with span("sweep.group") as sp:
-            for i in todo:
-                pt = points[i]
-                sig = (len(pt.task), pt.task.group_shape, pt.hw.X, pt.hw.Y,
-                       pt.hw.topology.n_entrances, _flow_width(pt),
-                       _strip_devices(pt.options))
-                groups.setdefault(sig, []).append(i)
-            sp.set_metadata(groups=len(groups))
-        for sig, idxs in groups.items():
-            outs = ga_jax.solve_islands(
-                [points[i].task for i in idxs],
-                [points[i].hw for i in idxs],
-                points[idxs[0]].options, objective, cfg,
-                devices=devices)
-            for i, out in zip(idxs, outs):
-                records[i] = out
+        return ga_jax.solve_islands(
+            *_group_args(group), objective, cfg,
+            devices=_call_devices(devices, cfg.devices))
 
-    if cache:
-        _store(records, todo, fps, _copy_solver_record)
-    return records
+    fam = _Family(
+        lambda pt: _solver_fingerprint(pt, "ga", backend, objective, cfg),
+        _copy_solver_record,
+        lambda pt: run_ga(pt.task, pt.hw, objective, pt.options, cfg,
+                          backend="numpy", engine="vectorized"),
+        _shape_key, batch, span="sweep.solve")
+    return _sweep(points, fam, backend == "jax", cache, *ckpt_args)
 
 
 # ------------------------------------------------- batched co-search
@@ -819,9 +812,8 @@ def cosearch_sweep(
     :class:`repro.core.cosearch.CoSearchResult` records aligned with
     ``points`` — also reachable as ``solve_grid(method="cosearch")``.
 
-    Uncached points are grouped by shape signature — (n_ops, X, Y,
-    n_entrances); the :class:`EvalOptions` statics live in the compiled
-    function's cache key — and each group evolves as islands of ONE
+    Uncached points are grouped by shape signature (:func:`_shape_key`,
+    static options included) and each group evolves as islands of ONE
     ``jit(vmap(scan))`` call
     (:func:`repro.core.cosearch.cosearch_islands`). A point's record is
     identical solo or batched (island RNG depends only on ``cfg.seed``,
@@ -853,45 +845,18 @@ def cosearch_sweep(
         raise ValueError(f"unknown backend {backend!r} for cosearch; "
                          f"the fused fitness only exists on 'jax' "
                          f"('auto' resolves to it)")
-    ckpt = _resolve_checkpoint(checkpoint, checkpoint_every)
-    if ckpt is not None:
-        if not cache:
-            raise ValueError("checkpointing requires cache=True — "
-                             "records persist through the result cache")
-        return _checkpointed(
-            points, ckpt, straggler,
-            lambda c: cosearch_sweep(c, objective, cfg, backend=backend,
-                                     cache=True, devices=devices))
-
-    norm_hws = [dataclasses.replace(pt.hw, diagonal_links=False)
-                for pt in points]
-    records, todo, fps = _lookup(
-        points, cache,
-        lambda i: _solver_fingerprint(
-            dataclasses.replace(points[i], hw=norm_hws[i]),
-            "cosearch", "jax", objective, cfg),
-        _copy_solver_record)
-
-    if todo:
-        groups: dict[tuple, list[int]] = {}
-        for i in todo:
-            pt = points[i]
-            sig = (len(pt.task), pt.hw.X, pt.hw.Y,
-                   pt.hw.topology.n_entrances, _flow_width(pt),
-                   _strip_devices(pt.options))
-            groups.setdefault(sig, []).append(i)
-        for sig, idxs in groups.items():
-            outs = cosearch_islands(
-                [points[i].task for i in idxs],
-                [norm_hws[i] for i in idxs],
-                points[idxs[0]].options, objective, cfg,
-                devices=devices)
-            for i, out in zip(idxs, outs):
-                records[i] = out
-
-    if cache:
-        _store(records, todo, fps, _copy_solver_record)
-    return records
+    points = [dataclasses.replace(
+        pt, hw=dataclasses.replace(pt.hw, diagonal_links=False))
+        for pt in points]
+    fam = _Family(
+        lambda pt: _solver_fingerprint(pt, "cosearch", "jax", objective,
+                                       cfg),
+        _copy_solver_record, None, _shape_key,
+        lambda group: cosearch_islands(
+            *_group_args(group), objective, cfg,
+            devices=_call_devices(devices, cfg.devices)))
+    return _sweep(points, fam, True, cache, checkpoint, checkpoint_every,
+                  straggler)
 
 
 # ---------------------------------------------- multi-tenant placement
@@ -959,34 +924,16 @@ def multitenant_sweep(
                         f"got {type(cfg).__name__}")
     if backend == "auto":
         backend = "jax"
-    if backend not in ("numpy", "jax"):
-        raise ValueError(f"unknown backend {backend!r}; "
-                         f"one of ('numpy', 'jax', 'auto')")
-    ckpt = _resolve_checkpoint(checkpoint, checkpoint_every)
-    if ckpt is not None:
-        if not cache:
-            raise ValueError("checkpointing requires cache=True — "
-                             "records persist through the result cache")
-        return _checkpointed(
-            points, ckpt, straggler,
-            lambda c: multitenant_sweep(c, objective, cfg,
-                                        backend=backend, cache=True,
-                                        devices=devices))
-    records, todo, fps = _lookup(
-        points, cache,
-        lambda i: _multitenant_fingerprint(points[i], backend, objective,
-                                           cfg),
-        _copy_solver_record)
-
-    for i in todo:
-        pt = points[i]
-        records[i] = solve_multitenant(
+    _check_backend(backend)
+    fam = _Family(
+        lambda pt: _multitenant_fingerprint(pt, backend, objective, cfg),
+        _copy_solver_record,
+        lambda pt: solve_multitenant(
             pt.tasks, pt.hw, objective, pt.options, cfg,
-            backend=backend, cache=cache, devices=devices)
-
-    if cache:
-        _store(records, todo, fps, _copy_solver_record)
-    return records
+            backend=backend, cache=cache, devices=devices),
+        None, None)
+    return _sweep(points, fam, False, cache, checkpoint, checkpoint_every,
+                  straggler)
 
 
 # ------------------------------------------------- batched pipelining
@@ -1056,113 +1003,66 @@ def pipeline_sweep(
 
     if cfg is None:
         cfg = PipelineConfig()
-    ckpt = _resolve_checkpoint(checkpoint, checkpoint_every)
-    if ckpt is not None:
-        if not cache:
-            raise ValueError("checkpointing requires cache=True — "
-                             "records persist through the result cache")
-        return _checkpointed(
-            points, ckpt, straggler,
-            lambda c: pipeline_sweep(c, cfg, backend=backend, cache=True,
-                                     devices=devices))
-    if devices is not None:
-        cfg = dataclasses.replace(cfg, devices=devices)
     engine = resolve_auto_pipeline_engine(cfg.engine)
-    # An explicit cfg.backend wins over the sweep-level default (the
-    # PipelineConfig contract); "auto" resolves to jax here — grid
-    # batching always wins, and the engines agree bit-for-bit, so the
-    # resolution is purely a performance choice.
     backend = cfg.backend if cfg.backend != "auto" else backend
     backend = "jax" if backend == "auto" else backend
-    if backend not in ("numpy", "jax"):
-        raise ValueError(f"unknown backend {backend!r}; "
-                         f"one of ('numpy', 'jax', 'auto')")
+    _check_backend(backend)
     if engine != "vectorized":
         backend = "numpy"        # serial engines run on host
     # Fingerprint the *resolved* config so auto-selected records share
     # the cache with their concrete equivalents (the §12 rule).
-    cfg = dataclasses.replace(cfg, engine=engine, backend=backend)
-    records, todo, fps = _lookup(
-        points, cache, lambda i: _pipeline_fingerprint(points[i], cfg),
-        _copy_solver_record)
+    cfg = dataclasses.replace(cfg, engine=engine, backend=backend,
+                              devices=_call_devices(devices, cfg.devices))
 
-    if todo and (engine != "vectorized" or backend == "numpy"):
-        for i in todo:
-            pt = points[i]
-            records[i] = pipeline_batch(pt.segments, pt.batch, config=cfg)
-    elif todo:
+    def batch(group):
         from . import pipelining_jax
 
-        groups: dict[tuple, list[int]] = {}
-        for i in todo:
-            pt = points[i]
-            groups.setdefault((len(pt.segments), int(pt.batch)),
-                              []).append(i)
-        for (n, B), idxs in groups.items():
-            durs = np.stack([points[i].durations() for i in idxs])
-            out = pipelining_jax.schedule_batch(durs, B,
-                                                devices=cfg.devices)
-            for g, i in enumerate(idxs):
-                records[i] = PipelineResult(
-                    B, sequential_makespan(points[i].segments, B),
-                    float(out["makespan"][g]), engine="vectorized")
+        B = int(group[0].batch)
+        out = pipelining_jax.schedule_batch(
+            np.stack([pt.durations() for pt in group]), B,
+            devices=cfg.devices)
+        return [PipelineResult(B, sequential_makespan(pt.segments, B),
+                               float(out["makespan"][g]),
+                               engine="vectorized")
+                for g, pt in enumerate(group)]
 
-    if cache:
-        _store(records, todo, fps, _copy_solver_record)
-    return records
+    fam = _Family(
+        lambda pt: _pipeline_fingerprint(pt, cfg), _copy_solver_record,
+        lambda pt: pipeline_batch(pt.segments, pt.batch, config=cfg),
+        lambda pt: (len(pt.segments), int(pt.batch)), batch)
+    return _sweep(points, fam, backend == "jax", cache, checkpoint,
+                  checkpoint_every, straggler)
 
 
-def _solve_grid_miqp(points, objective, cfg, backend, cache,
-                     devices=None) -> list:
+def _solve_grid_miqp(points, objective, cfg, backend, cache, devices,
+                     checkpoint, checkpoint_every, straggler) -> list:
     """``solve_grid`` body for ``method="miqp"`` (DESIGN.md §12)."""
-    import dataclasses as _dc
-
     from .evaluator import resolve_auto_backend
     from .miqp import MIQPConfig, resolve_auto_engine, run_miqp
 
     if cfg is None:
         cfg = MIQPConfig()
-    if devices is not None:
-        cfg = _dc.replace(cfg, devices=devices)
     engine = resolve_auto_engine(cfg.engine)
     backend = (resolve_auto_backend(backend, cfg.score_chunk)
                if engine == "lattice" else "numpy")
-    if backend not in ("numpy", "jax"):
-        raise ValueError(f"unknown backend {backend!r}; "
-                         f"one of ('numpy', 'jax', 'auto')")
+    _check_backend(backend)
     # Fingerprint the *resolved* config so auto-selected records share
     # the cache with their concrete equivalents.
-    cfg = _dc.replace(cfg, engine=engine, backend=backend)
-    records, todo, fps = _lookup(
-        points, cache,
-        lambda i: _solver_fingerprint(points[i], "miqp", backend, objective,
-                                      cfg),
-        _copy_solver_record)
+    cfg = dataclasses.replace(cfg, engine=engine, backend=backend,
+                              devices=_call_devices(devices, cfg.devices))
 
-    if todo and (engine == "milp" or backend == "numpy"):
-        # milp cannot batch; the numpy lattice is the per-point reference.
-        for i in todo:
-            pt = points[i]
-            records[i] = run_miqp(pt.task, pt.hw, objective, pt.options,
-                                  cfg, engine=engine)
-    elif todo:
+    def batch(group):
         from . import miqp_jax
 
-        groups: dict[tuple, list[int]] = {}
-        for i in todo:
-            pt = points[i]
-            sig = (len(pt.task), pt.task.group_shape, pt.hw.X, pt.hw.Y,
-                   pt.hw.topology.n_entrances, _flow_width(pt),
-                   _strip_devices(pt.options))
-            groups.setdefault(sig, []).append(i)
-        for sig, idxs in groups.items():
-            outs = miqp_jax.solve_lattice_batch(
-                [points[i].task for i in idxs],
-                [points[i].hw for i in idxs],
-                points[idxs[0]].options, objective, cfg)
-            for i, out in zip(idxs, outs):
-                records[i] = out
+        return miqp_jax.solve_lattice_batch(*_group_args(group), objective,
+                                            cfg)
 
-    if cache:
-        _store(records, todo, fps, _copy_solver_record)
-    return records
+    # milp cannot batch; the numpy lattice is the per-point reference.
+    fam = _Family(
+        lambda pt: _solver_fingerprint(pt, "miqp", backend, objective, cfg),
+        _copy_solver_record,
+        lambda pt: run_miqp(pt.task, pt.hw, objective, pt.options, cfg,
+                            engine=engine),
+        _shape_key, batch)
+    return _sweep(points, fam, backend == "jax", cache, checkpoint,
+                  checkpoint_every, straggler)

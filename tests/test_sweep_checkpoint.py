@@ -6,6 +6,7 @@ after K checkpoint appends — no cleanup, no atexit) and asserts the
 restarted run recomputes only the tail, bitwise-identically to an
 uninterrupted run. Children use the numpy backend: no jax import, so
 they start in milliseconds."""
+import dataclasses
 import os
 import subprocess
 import sys
@@ -14,9 +15,13 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.core import EvalOptions, GemmOp, Task, make_hw
+from repro.core import (CoSearchConfig, EvalOptions, GemmOp,
+                        MultiTenantConfig, Task, make_hw)
 from repro.core import sweep
 from repro.core.ga import GAConfig
+from repro.core.miqp import MIQPConfig
+from repro.core.netsim import MeshNet
+from repro.core.pipelining import PipelineConfig
 from repro.runtime.fault_tolerance import StragglerMonitor
 
 SRC = os.path.join(os.path.dirname(__file__), os.pardir, "src")
@@ -44,26 +49,128 @@ def _points(k=6):
 
 
 # ------------------------------------------------- in-process semantics
-def test_checkpoint_requires_cache():
+GA_CFG = GAConfig(population=16, generations=2, seed=1)
+CO_CFG = CoSearchConfig(population=4, generations=2, seed_steps=2,
+                        seed_starts=2)
+MIQP_CFG = MIQPConfig(engine="lattice", backend="numpy")
+MT_CFG = MultiTenantConfig(method="uniform")
+PIPE_CFG = PipelineConfig(engine="vectorized", backend="numpy")
+FAMILIES = ["eval", "netsim", "ga", "miqp", "cosearch", "multitenant",
+            "pipeline"]
+
+
+def _family(name):
+    """One sweep family's ``(points, run, fingerprint)``: ``run(points,
+    **kw)`` sweeps them on the host (co-search on a small jax config, its
+    only backend) and ``fingerprint(pt)`` is the cache key a point's
+    record is stored under."""
+    pts = _points(4)
+    if name == "eval":
+        return (pts,
+                lambda p, **kw: sweep.eval_sweep(p, backend="numpy", **kw),
+                lambda pt: sweep._point_fingerprint(pt, "numpy"))
+    if name == "netsim":
+        return ([MeshNet(2, 2, 64.0 + i, 128.0, [0]) for i in range(4)],
+                lambda p, **kw: sweep.netsim_sweep(p, 1e6, backend="numpy",
+                                                   **kw),
+                lambda net: sweep._netsim_fingerprint(net, 1e6, "numpy"))
+    if name == "ga":
+        return (pts,
+                lambda p, **kw: sweep.solve_grid(p, "latency", GA_CFG,
+                                                 backend="numpy", **kw),
+                lambda pt: sweep._solver_fingerprint(pt, "ga", "numpy",
+                                                     "latency", GA_CFG))
+    if name == "miqp":
+        return (pts,
+                lambda p, **kw: sweep.solve_grid(p, "latency", MIQP_CFG,
+                                                 backend="numpy",
+                                                 method="miqp", **kw),
+                lambda pt: sweep._solver_fingerprint(pt, "miqp", "numpy",
+                                                     "latency", MIQP_CFG))
+    if name == "cosearch":
+        # odd points have diagonal links: the sweep keys them as plain
+        diag = [sweep.EvalPoint(pt.task, dataclasses.replace(
+            pt.hw, diagonal_links=bool(i % 2)), pt.options)
+            for i, pt in enumerate(pts)]
+        return (diag,
+                lambda p, **kw: sweep.cosearch_sweep(p, "edp", CO_CFG, **kw),
+                lambda pt: sweep._solver_fingerprint(
+                    dataclasses.replace(pt, hw=dataclasses.replace(
+                        pt.hw, diagonal_links=False)),
+                    "cosearch", "jax", "edp", CO_CFG))
+    if name == "multitenant":
+        tenants = (toy_task(2, 256), toy_task(2, 512))
+        return ([sweep.MultiTenantPoint(tenants, pt.hw) for pt in pts],
+                lambda p, **kw: sweep.multitenant_sweep(
+                    p, "edp", MT_CFG, backend="numpy", **kw),
+                lambda pt: sweep._multitenant_fingerprint(pt, "numpy", "edp",
+                                                          MT_CFG))
+    assert name == "pipeline"
+    return ([sweep.PipelinePoint([(f"g{j}", 1.0 + i, 2.0, 0.5 * j)
+                                  for j in range(3)], 4) for i in range(4)],
+            lambda p, **kw: sweep.pipeline_sweep(p, PIPE_CFG, **kw),
+            lambda pt: sweep._pipeline_fingerprint(pt, PIPE_CFG))
+
+
+def _bitwise(a, b) -> bool:
+    """Records equal down to the last bit, whatever their family."""
+    if isinstance(a, np.ndarray):
+        return (a.dtype == b.dtype and a.shape == b.shape
+                and a.tobytes() == b.tobytes())
+    if isinstance(a, float):
+        return isinstance(b, float) and a.hex() == b.hex()
+    if isinstance(a, dict):
+        return a.keys() == b.keys() and all(_bitwise(a[k], b[k]) for k in a)
+    if isinstance(a, (list, tuple)):
+        return len(a) == len(b) and all(map(_bitwise, a, b))
+    if dataclasses.is_dataclass(a):
+        return type(a) is type(b) and all(
+            _bitwise(getattr(a, f.name), getattr(b, f.name))
+            for f in dataclasses.fields(a))
+    return a == b
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_checkpoint_requires_cache(family, tmp_path):
+    points, run, _ = _family(family)
     with pytest.raises(ValueError, match="cache=True"):
-        sweep.eval_sweep(_points(2), backend="numpy", cache=False,
-                         checkpoint="/tmp/unused-store.bin")
+        run(points[:2], cache=False, checkpoint=str(tmp_path / "store.bin"))
 
 
-def test_eval_sweep_checkpoint_roundtrip(tmp_path):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_checkpoint_roundtrip(family, tmp_path):
+    """Every family resumes from its store: a second run is all hits, its
+    records bitwise the first run's; the straggler monitor sees each
+    checkpoint chunk."""
     path = str(tmp_path / "store.bin")
-    pts = _points(6)
-    recs = sweep.eval_sweep(pts, backend="numpy", checkpoint=path,
-                            checkpoint_every=2)
-    assert sweep.cache_stats() == {"hits": 0, "misses": 6}
+    points, run, _ = _family(family)
+    mon = StragglerMonitor()
+    recs = run(points, checkpoint=path, checkpoint_every=2, straggler=mon)
+    assert mon.ewma > 0            # observed per-chunk wall-times
+    if family != "multitenant":    # its inner sweeps count lookups too
+        assert sweep.cache_stats() == {"hits": 0, "misses": len(points)}
     sweep.clear_cache()
-    recs2 = sweep.eval_sweep(pts, backend="numpy", checkpoint=path,
-                             checkpoint_every=2)
+    recs2 = run(points, checkpoint=path, checkpoint_every=2)
     # the store held every record: pure resume, zero recomputation
-    assert sweep.cache_stats() == {"hits": 6, "misses": 0}
-    for a, b in zip(recs, recs2):
-        assert a["latency"] == b["latency"]
-        assert np.array_equal(a["t_in"], b["t_in"])
+    assert sweep.cache_stats() == {"hits": len(points), "misses": 0}
+    assert all(r is not None for r in recs2)
+    assert all(_bitwise(a, b) for a, b in zip(recs, recs2))
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_cache_keys_are_family_fingerprints(family):
+    """The keys a sweep leaves in the cache are its family's fingerprints
+    of the points, so a store written by an earlier driver still
+    resumes."""
+    points, run, fingerprint = _family(family)
+    run(points)
+    keys = set(sweep.export_cache())
+    fps = {fingerprint(pt) for pt in points}
+    assert len(fps) == len(points)
+    tag = next(iter(fps))[0]
+    assert {k for k in keys if k[0] == tag} == fps
+    if family != "multitenant":    # its inner sweeps store their own
+        assert keys == fps
 
 
 def test_partial_store_resumes_tail_only(tmp_path):
@@ -73,21 +180,6 @@ def test_partial_store_resumes_tail_only(tmp_path):
     sweep.clear_cache()
     sweep.eval_sweep(pts, backend="numpy", checkpoint=path)
     assert sweep.cache_stats() == {"hits": 4, "misses": 2}
-
-
-def test_solve_grid_checkpoint_and_straggler(tmp_path):
-    path = str(tmp_path / "store.bin")
-    pts = _points(4)
-    cfg = GAConfig(population=16, generations=2, seed=1)
-    mon = StragglerMonitor()
-    sweep.solve_grid(pts, "latency", cfg, backend="numpy",
-                     checkpoint=path, checkpoint_every=2, straggler=mon)
-    assert mon.ewma > 0            # observed per-chunk wall-times
-    sweep.clear_cache()
-    recs = sweep.solve_grid(pts, "latency", cfg, backend="numpy",
-                            checkpoint=path, checkpoint_every=2)
-    assert sweep.cache_stats() == {"hits": 4, "misses": 0}
-    assert all(r is not None for r in recs)
 
 
 def test_straggler_flag_emits_stderr_line(tmp_path, capsys):
